@@ -70,6 +70,8 @@ def main() -> None:
                          "`python -m repro.obs render <file>`)")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_convergence, bench_io, bench_kernels,
                             bench_ooc, bench_runtime, bench_serving,
                             bench_streaming)

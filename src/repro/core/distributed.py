@@ -1,5 +1,5 @@
 """shard_map execution of the structure-aware engine (paper Alg. 3's
-master/mirror update, DESIGN.md §5).
+master/mirror update).
 
 Topology: the schedule width W = (devices on the data axis) x
 (blocks-per-device). Each device runs its assigned blocks *sequentially*
@@ -17,9 +17,8 @@ one device per call).
 
 Cross-device visibility of hot updates happens at call boundaries — the same
 relaxation PowerSwitch makes when it distributes its async mode. Vertex state
-is replicated per device here (it is O(n) floats); for graphs whose state
-exceeds a device, DESIGN.md §5 describes the sharded-state variant (boundary
-deltas only).
+is replicated per device here (it is O(n) floats); graphs whose state
+exceeds a device need a sharded-state variant (boundary deltas only).
 """
 from __future__ import annotations
 
@@ -29,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.algorithms import VertexProgram
@@ -139,13 +137,25 @@ class DistributedEngine(StructureAwareEngine):
             return values_out, reconcile(psd_l, psd_in), \
                 reconcile(dmax_l, dmax_in)
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             device_run, mesh=self.mesh,
             in_specs=(P(), P(), P(), P(self.axis), P(self.axis)),
-            out_specs=(P(), P(), P()), check_rep=False)
+            out_specs=(P(), P(), P()), check_vma=False)
         fn = jax.jit(smapped, donate_argnums=(0, 1, 2))
         self._fns[key] = fn
         return fn
+
+    def compiled_hot_dispatch(self) -> jax.stages.Compiled:
+        """The compiled program of one hot-group dispatch — what a caller
+        inspects to see how the work is laid out over the mesh
+        (``input_shardings`` of the block rows, collectives in
+        ``as_text()``)."""
+        ps = (self.plan.num_blocks, self.config.subblocks)
+        w = self.ndev * self.bpd
+        return self._get_fn("hot", True).lower(
+            jnp.zeros(self._values_len, jnp.float32),
+            jnp.zeros(ps, jnp.float32), jnp.zeros(ps, jnp.float32),
+            jnp.zeros(w, jnp.int32), jnp.zeros(w, bool)).compile()
 
     def _dispatch(self, values, psd, dmax, block_ids: np.ndarray,
                   sequential: bool, width: int | None = None):

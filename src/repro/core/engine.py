@@ -141,7 +141,7 @@ class EngineConfig:
     t2: float = 1e-6  # paper's default convergence threshold
     max_iterations: int = 100000
     stale_eps: float = 1e-12  # PSD above this marks downstream blocks dirty
-    use_pallas: bool = False  # sum-combine via the Pallas spmv kernel
+    use_pallas: bool = False  # block sweeps combine in the Pallas kernel
     fused: bool = True  # device-resident lax.while_loop superstep
     adaptive: bool = True  # active-set execution (False = fixed-slate)
     subblocks: int = 1  # sub-blocks per block (hierarchical activity tracking)
@@ -1317,26 +1317,38 @@ class StructureAwareEngine:
         self._fns[key] = fn
         return fn
 
-    def prewarm_buckets(self) -> list[int]:
-        """Compile the fused chunk for every dispatch-width bucket with a
-        zero-length run (it_end == it0: the while_loop body never fires),
-        so a long-lived caller (streaming, benchmarks) never pays a bucket
-        compile inside a measured batch/run. Returns the widths warmed."""
+    def _idle_chunk_args(self, wb: int) -> tuple:
+        """Arguments of a zero-length chunk call (it_end == it0: the
+        while_loop body never fires) at dispatch width ``wb``."""
         p = self.plan
         ps = (p.num_blocks, self.config.subblocks)
+        return (self._ed, self._coupling_dev,
+                jnp.zeros(self._values_len, jnp.float32),
+                jnp.zeros(ps, jnp.float32),
+                jnp.zeros(ps, jnp.float32),
+                jnp.zeros(ps, jnp.int32),
+                jnp.zeros(p.num_blocks, jnp.int32),
+                jnp.zeros(wb, jnp.int32), jnp.int32(0), jnp.int32(0),
+                jnp.int32(0),
+                jnp.zeros(p.num_blocks, dtype=bool),
+                jnp.int32(self.config.i2))
+
+    def prewarm_buckets(self) -> list[int]:
+        """Compile the fused chunk for every dispatch-width bucket with a
+        zero-length run, so a long-lived caller (streaming, benchmarks)
+        never pays a bucket compile inside a measured batch/run. Returns
+        the widths warmed."""
         for wb in self._ladder:
-            fn = self._get_chunk(wb)
-            fn(self._ed, self._coupling_dev,
-               jnp.zeros(self._values_len, jnp.float32),
-               jnp.zeros(ps, jnp.float32),
-               jnp.zeros(ps, jnp.float32),
-               jnp.zeros(ps, jnp.int32),
-               jnp.zeros(p.num_blocks, jnp.int32),
-               jnp.zeros(wb, jnp.int32), jnp.int32(0), jnp.int32(0),
-               jnp.int32(0),
-               jnp.zeros(p.num_blocks, dtype=bool),
-               jnp.int32(self.config.i2))
+            self._get_chunk(wb)(*self._idle_chunk_args(wb))
         return list(self._ladder)
+
+    def compiled_chunk_text(self) -> str:
+        """The compiled fused chunk's program text at the widest dispatch
+        bucket — what a caller inspects to see which kernels the backend
+        runs (``tpu_custom_call`` for Pallas)."""
+        wb = self._ladder[0]
+        return self._get_chunk(wb).lower(
+            *self._idle_chunk_args(wb)).compile().as_text()
 
     # -- main loop ----------------------------------------------------------
     def run(self, max_iterations: int | None = None,

@@ -1,36 +1,36 @@
-"""Pallas TPU kernel family: the fused VMEM-resident block sweep.
+"""Pallas TPU kernel family: the block sweep's segmented combine.
 
-One ``pallas_call`` per scheduled block fuses the whole per-block update —
-edge-tile gather → ``edge_map`` → segmented combine → ``apply`` — that the
-dense engine expresses as a ``fori_loop`` of HLO gathers and serial
-scatters (``make_tiled_processor`` / ``make_lane_processor``). The grid
-walks the block's tile rows (scalar-prefetched ``[t0, tile_cnt, base]``
-drives a dynamic index map into the SHARED tile arrays, so every block
-reuses one executable); each step streams one ``(1, TILE)`` edge tile
-HBM→VMEM while the ``(1, C)`` — or ``(C, L)`` lane — accumulator stays
-VMEM-resident across the whole loop (the accumulator pattern proven in
-``spmv.py``). ``apply`` runs in-kernel at the last grid step, so HBM
-traffic per block is exactly E edge reads + C·L value writes: the paper's
-cache-block residency claim, realized literally.
+The engines' per-block update is gather -> ``edge_map`` -> segmented
+combine -> ``apply`` over the block's run of ``(TILE,)`` edge tile rows.
+The dense path expresses it as a ``fori_loop`` of HLO gathers and serial
+scatters (``make_tiled_processor`` / ``make_lane_processor``).
+``make_block_sweep`` keeps the gather and ``edge_map`` in XLA, because a
+gather from the whole vertex array has no Mosaic lowering, and moves the
+segmented combine into one ``pallas_call`` per chunk of
+:data:`CHUNK_TILES` tile rows:
 
-Combine families:
+- ``sum`` — a one-hot matmul on the MXU, ``(L, TILE) @ (TILE, C)`` per
+  tile row at ``Precision.HIGHEST``, single-lane (L = 1) and
+  lane-batched (the PPR lanes) alike;
+- ``min`` / ``max`` — a masked select against the same one-hot and a
+  reduce over the tile axis per lane; exact in any order, so SSSP, BFS
+  and CC stay bitwise.
 
-- ``sum`` — one-hot matmul on the MXU, ``(1, E_t) @ (E_t, C)`` single-lane
-  or ``(C, E_t) @ (E_t, L)`` lane-batched (the PPR scatter fix).
-- ``min`` / ``max`` — masked select against a broadcast one-hot then a
-  tree reduce over the tile axis; exact (order-independent), so SSSP/BFS
-  stay bitwise.
+Per chunk the kernel reads ``K * L * TILE`` messages, ``K * TILE`` slot
+offsets and the ``(L, C)`` aggregate, and writes the aggregate back; the
+XLA side reads the chunk's four tile rows (src, dst, w, valid) and
+gathers the source values. The chunk loop runs the block's own
+``ceil(tile_cnt / K)`` steps, so a block costs its true edge count, not
+the largest block's.
 
-Sub-block activity (``subblocks = S > 1``) is honored INSIDE the kernel:
-``sub_act`` rides the scalar-prefetch vector and a tile whose ``cov`` row
-misses every live sub-range leaves the accumulator untouched — the same
-identity branch the dense ``lax.cond`` takes, so parity is by
-construction, not by rounding luck.
+Sub-block activity (``subblocks = S > 1``) masks whole tile rows whose
+``cov`` misses every live sub-range, and a chunk with no live row skips
+the kernel — the dense path's identity branch.
 
-Everything here is bitwise-identical to the dense reference on this
-backend (property-tested in ``tests/test_block_sweep.py``); the dense
-path remains the oracle. ``interpret=True`` runs the same kernels under
-the Pallas interpreter on CPU.
+Parity with the dense oracle is tested in ``tests/test_block_sweep.py``:
+min/max bitwise, sum within :func:`sum_tolerance`. ``interpret=True``
+runs the kernels under the Pallas interpreter on CPU;
+``tests/test_chip_compile.py`` compiles them for a v5e chip.
 """
 from __future__ import annotations
 
@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.contracts import one_executable_per
 
@@ -118,88 +117,90 @@ def edge_block_max(msg, dst, block_size: int, identity: float,
                               tile_e=tile_e, interpret=interpret)
 
 
-# -- the fused block sweep ---------------------------------------------------
-def _sweep_kernel(s_ref, *refs, edge_map, apply_fn, combine: str,
-                  identity: float, tile: int, c: int, n_total: int,
-                  t_max: int, lanes: bool, masked: bool):
-    """Grid = the block's tile rows. s_ref (scalar prefetch, SMEM) is
-    ``[t0, tile_cnt, base]`` (+ ``sub_act`` as int32 when masked); tile
-    refs are ``(1, tile)`` VMEM blocks selected by the dynamic index map;
-    values/aux (and vconst for lanes) are whole-array ANY refs (the gather
-    needs random access across block boundaries); agg/new are VMEM-
-    resident accumulator outputs revisited by every step."""
-    if masked:
-        src_ref, dstl_ref, w_ref, valid_ref, cov_ref, *rest = refs
-    else:
-        src_ref, dstl_ref, w_ref, valid_ref, *rest = refs
-        cov_ref = None
-    if lanes:
-        values_ref, aux_ref, vconst_ref, agg_ref, new_ref = rest
-    else:
-        values_ref, aux_ref, agg_ref, new_ref = rest
-        vconst_ref = None
+# -- the block sweep -----------------------------------------------------------
+# tile rows per combine call: a block's tile run is walked in chunks of this
+# many rows (the last chunk masked), so a 3-tile block pays one call and the
+# hub block amortizes the per-call cost over 8 tiles
+CHUNK_TILES = 8
 
-    t = pl.program_id(0)
-    s = s_ref[...]
 
-    @pl.when(t == 0)
-    def _init():
-        agg_ref[...] = jnp.full_like(agg_ref, identity)
+def sum_tolerance(terms):
+    """Relative bound on the gap between the kernel's MXU sum and the
+    dense scatter for a slot that sums ``terms`` f32 terms of one sign.
 
-    active = t < s[1]
-    if masked:
-        # the dense path's lax.cond identity branch, in-kernel: a tile
-        # whose covered sub-ranges are all masked must leave agg untouched
-        active = active & (cov_ref[0, :] & (s[3:] > 0)).any()
+    Under ``Precision.HIGHEST`` the MXU splits each f32 message into three
+    bf16 parts whose sum is exact, and the one-hot factor is exact, so
+    every product is exact; the two paths add the same values in
+    different orders. Any order of an m-term sum is within ``(m - 1) u``
+    of the exact sum (u = 2^-24), the MXU's over 3m parts: the two
+    results are within ``6 m u`` of each other, relative to the slot.
+    """
+    return 6.0 * np.maximum(terms, 1) * 2.0 ** -24
 
-    @pl.when(active)
-    def _accumulate():
-        vals = values_ref[...]
-        auxv = aux_ref[...]
-        e_src = src_ref[0, :]
-        msg = edge_map(vals[e_src], auxv[e_src], w_ref[0, :])
-        valid = valid_ref[0, :]
-        msg = jnp.where(valid[:, None] if lanes else valid, msg, identity)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (tile, c), 1)
-        onehot = dstl_ref[0, :].reshape(tile, 1) == cols
+
+def _combine_kernel(agg_ref, msg_ref, dst_ref, out_ref, *, combine: str,
+                    identity: float, edge_order: bool):
+    """Fold one chunk of a block's tile run into the block's aggregate.
+
+    ``agg_ref``/``out_ref`` are the lane-major ``(L, C)`` aggregate
+    before/after the chunk. ``msg_ref`` is ``(K, L, TILE)`` f32 (masked
+    slots already carry the combine identity) and ``dst_ref`` the
+    ``(K, 1, TILE)`` int32 slot offsets. Tile rows fold into the aggregate
+    one at a time, in row order, exactly as the dense path merges its
+    per-tile partials. Per row the one-hot ``hit[e, c] = dst[e] == c``
+    (``(TILE, C)``) is built on the VPU:
+
+    - ``sum``: ``msg @ hit`` on the MXU, ``(L, TILE) @ (TILE, C)``, at
+      ``Precision.HIGHEST``: the one-hot is exact in bf16 and the message
+      splits into three bf16 terms, so every product is exact and only
+      the order of the f32 additions differs from the scatter
+      (:func:`sum_tolerance`). With ``edge_order`` (the interpreter) the row
+      is scatter-added into the aggregate in edge order — the dense
+      path's arithmetic — so CPU runs stay bitwise equal to it;
+    - ``min`` / ``max``: a masked select and a reduce over the tile axis
+      per lane — exact in any order, so bitwise equal to the scatter.
+    """
+    k, nl, tile = msg_ref.shape
+    c = out_ref.shape[1]
+    slots = lax.broadcasted_iota(jnp.int32, (tile, c), 1)
+    lane_of = lax.broadcasted_iota(jnp.int32, (nl, c), 0)
+    acc = agg_ref[...]
+    for j in range(k):
+        msg = msg_ref[j]
+        if combine == "sum" and edge_order:
+            acc = acc.T.at[dst_ref[j][0]].add(msg.T).T
+            continue
+        hit = dst_ref[j].reshape(tile, 1) == slots
         if combine == "sum":
-            ohf = onehot.astype(jnp.float32)
-            if lanes:
-                # one (1, E_t) @ (E_t, C) matmul per lane (L is static at
-                # trace time). A single (C, E_t) @ (E_t, L) gemm is the
-                # higher-arithmetic-intensity MXU form, but its reduction
-                # blocking reassociates the sum (~1e-7 drift) — the gemv
-                # shape accumulates in edge order, which keeps the lane
-                # path bitwise vs the scatter reference
-                agg_ref[...] += jnp.stack(
-                    [jnp.dot(msg[:, lane].reshape(1, tile), ohf,
-                             preferred_element_type=jnp.float32).reshape(c)
-                     for lane in range(msg.shape[1])], axis=1)
-            else:
-                agg_ref[...] += jnp.dot(msg.reshape(1, tile), ohf,
-                                        preferred_element_type=jnp.float32)
-        else:
-            mer = jnp.minimum if combine == "min" else jnp.maximum
-            if lanes:
-                sel = jnp.where(onehot[:, :, None], msg[:, None, :],
-                                identity)
-            else:
-                sel = jnp.where(onehot, msg.reshape(tile, 1), identity)
-            red = sel.min(axis=0) if combine == "min" else sel.max(axis=0)
-            agg_ref[...] = mer(agg_ref[...],
-                               red if lanes else red.reshape(1, c))
+            acc = acc + jnp.dot(msg, hit.astype(jnp.float32),
+                                precision=lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+            continue
+        red = jnp.min if combine == "min" else jnp.max
+        mer = jnp.minimum if combine == "min" else jnp.maximum
+        for lane in range(nl):
+            col = msg[lane:lane + 1, :].reshape(tile, 1)
+            row = red(jnp.where(hit, col, identity), axis=0, keepdims=True)
+            acc = jnp.where(lane_of == lane, mer(acc, row), acc)
+    out_ref[...] = acc
 
-    @pl.when(t == t_max - 1)
-    def _apply():
-        base = s[2]
-        if lanes:
-            old = values_ref[pl.ds(base, c), :]
-            vc = vconst_ref[pl.ds(base, c), :]
-            new_ref[...] = apply_fn(old, agg_ref[...], vc, n_total)
-        else:
-            old = values_ref[pl.ds(base, c)]
-            new = apply_fn(old, agg_ref[...].reshape(c), n_total)
-            new_ref[...] = new.reshape(1, c)
+
+def _combine_call(agg, msg, dst, *, combine: str, identity: float,
+                  interpret: bool, edge_order: bool | None = None):
+    """``(L, C)`` aggregate + ``(K, L, TILE)`` messages + ``(K, 1, TILE)``
+    slots -> the ``(L, C)`` aggregate after the chunk. The operands are a
+    few hundred KiB, so they sit whole in VMEM: no grid, no block index
+    maps (vmap over the cold slate adds the grid). ``edge_order`` defaults
+    to ``interpret``."""
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, combine=combine,
+                          identity=identity,
+                          edge_order=(interpret if edge_order is None
+                                      else edge_order)),
+        out_shape=jax.ShapeDtypeStruct(agg.shape, jnp.float32),
+        interpret=interpret,
+        name="block_sweep",
+    )(agg, msg, dst)
 
 
 def _cache_put(key, sweep):
@@ -213,7 +214,7 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
                      tile_w: int, block_size: int, n_total: int,
                      subblocks: int = 1, lanes: bool = False,
                      interpret: bool = True):
-    """Build the fused sweep for one program over one tile geometry.
+    """Build the block sweep for one program over one tile geometry.
 
     Returns ``sweep(ed, values, row[, sub_act])`` — or
     ``sweep(ed, values, vconst, row[, sub_act])`` with ``lanes=True`` —
@@ -222,6 +223,19 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
     before their delta tails). Memoized per (program, geometry, mode) so
     repeated processor builds reuse one closure and the downstream jit
     caches stay warm.
+
+    The block's tile run is walked in chunks of :data:`CHUNK_TILES` rows
+    by an XLA loop whose trip count is the block's own
+    ``ceil(tile_cnt / K)``. Each step gathers the chunk's source values
+    and applies ``edge_map`` in XLA (a vertex-array gather has no Mosaic
+    lowering), masks invalid slots, rows past the block's run and — with
+    ``subblocks > 1`` — rows whose covered sub-ranges are all inactive
+    to the identity, and hands the chunk to :func:`_combine_kernel`.
+    ``apply`` runs in XLA on the block's aggregate.
+
+    Parity with the dense scatter path: ``min``/``max`` are bitwise equal;
+    each ``sum`` slot is within :func:`sum_tolerance` of the scatter's
+    (bitwise under the interpreter, which sums in edge order).
     """
     ts = np.asarray(tile_start, dtype=np.int32)
     tc = np.asarray(tile_cnt, dtype=np.int32)
@@ -234,92 +248,67 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
 
     c = block_size
     tile = tile_w
-    t_max = int(tc.max()) if tc.size else 0
+    k = CHUNK_TILES
     masked = subblocks > 1
     is_sum = program.combine == "sum"
+    ident = float(program.identity)
     t0_d = jnp.asarray(ts)
     tc_d = jnp.asarray(tc)
+    combine = functools.partial(_combine_call, combine=program.combine,
+                                identity=ident, interpret=interpret)
 
-    if t_max == 0 or n_tiles == 0:
-        # no tiles anywhere: the dense fori is a no-op, only apply runs
-        if lanes:
-            def sweep(ed, values, vconst, row, sub_act=None):
-                nl = values.shape[1]
-                base = row * c
-                old = lax.dynamic_slice(values, (base, 0), (c, nl))
-                vc = lax.dynamic_slice(vconst, (base, 0), (c, nl))
-                agg0 = (jnp.zeros((c, nl), jnp.float32) if is_sum
-                        else jnp.full((c, nl), program.identity))
-                return program.apply(old, agg0, vc, n_total)
-        else:
-            def sweep(ed, values, row, sub_act=None):
-                base = row * c
-                old = lax.dynamic_slice(values, (base,), (c,))
-                agg0 = (jnp.zeros(c, jnp.float32) if is_sum
-                        else jnp.full(c, program.identity))
-                return program.apply(old, agg0, n_total)
-        _cache_put(key, sweep)
-        return sweep
+    def block_agg(ed, values, row, sub_act):
+        nl = values.shape[1] if lanes else 1
+        t0, cnt = t0_d[row], tc_d[row]
+        agg0 = jnp.full((nl, c), 0.0 if is_sum else ident, jnp.float32)
+        if n_tiles == 0:
+            return agg0
 
-    kern = functools.partial(
-        _sweep_kernel, edge_map=program.edge_map, apply_fn=program.apply,
-        combine=program.combine, identity=float(program.identity),
-        tile=tile, c=c, n_total=n_total, t_max=t_max, lanes=lanes,
-        masked=masked)
+        def chunk(i, agg):
+            j = i * k + jnp.arange(k, dtype=jnp.int32)
+            r = jnp.minimum(t0 + j, n_tiles - 1)
+            live = j < cnt
+            if masked:
+                live = live & (ed.cov[r] & sub_act).any(axis=1)
 
-    def _tile_map(t, s):
-        # clamped so inactive trailing steps (t >= tile_cnt) prefetch a
-        # real row; @pl.when masks their contribution
-        return (jnp.minimum(s[0] + t, n_tiles - 1), 0)
+            def accumulate(agg):
+                ok = ed.valid[r] & live[:, None]
+                src = ed.src[r]
+                if lanes:
+                    flat = src.reshape(-1)
+                    msg = program.edge_map(values[flat], ed.aux[flat],
+                                           ed.w[r].reshape(-1))
+                    msg = jnp.where(ok.reshape(-1, 1), msg, ident)
+                    msg = msg.reshape(k, tile, nl).transpose(0, 2, 1)
+                else:
+                    msg = program.edge_map(values[src], ed.aux[src],
+                                           ed.w[r])
+                    msg = jnp.where(ok, msg, ident)[:, None, :]
+                return combine(agg, msg.astype(jnp.float32),
+                               ed.dstl[r][:, None, :])
 
-    tile_spec = pl.BlockSpec((1, tile), _tile_map)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+            if not masked:
+                return accumulate(agg)
+            # a chunk whose rows are all masked leaves agg untouched, the
+            # dense path's identity branch (a real skip in the hot sweep)
+            return lax.cond(live.any(), accumulate, lambda a: a, agg)
 
-    def call(scalars, operands, nl):
-        in_specs = [tile_spec] * 4
-        if masked:
-            in_specs.append(pl.BlockSpec((1, subblocks), _tile_map))
-        in_specs += [any_spec, any_spec]  # values, aux
-        if lanes:
-            in_specs.append(any_spec)  # vconst
-            out_shape = jax.ShapeDtypeStruct((c, nl), jnp.float32)
-            out_spec = pl.BlockSpec((c, nl), lambda t, s: (0, 0))
-        else:
-            out_shape = jax.ShapeDtypeStruct((1, c), jnp.float32)
-            out_spec = pl.BlockSpec((1, c), lambda t, s: (0, 0))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(t_max,), in_specs=in_specs,
-            out_specs=[out_spec, out_spec])
-        _, new = pl.pallas_call(
-            kern, grid_spec=grid_spec, out_shape=[out_shape, out_shape],
-            interpret=interpret)(scalars, *operands)
-        return new
+        return lax.fori_loop(0, (cnt + k - 1) // k, chunk, agg0)
 
     if lanes:
         def sweep(ed, values, vconst, row, sub_act=None):
             nl = values.shape[1]
-            scal = jnp.stack([t0_d[row], tc_d[row],
-                              row * c]).astype(jnp.int32)
-            if masked:
-                scal = jnp.concatenate([scal, sub_act.astype(jnp.int32)])
-                operands = (ed.src, ed.dstl, ed.w, ed.valid, ed.cov,
-                            values, ed.aux, vconst)
-            else:
-                operands = (ed.src, ed.dstl, ed.w, ed.valid,
-                            values, ed.aux, vconst)
-            return call(scal, operands, nl)
+            base = row * c
+            old = lax.dynamic_slice(values, (base, 0), (c, nl))
+            vc = lax.dynamic_slice(vconst, (base, 0), (c, nl))
+            agg = block_agg(ed, values, row, sub_act)
+            return program.apply(old, agg.T, vc, n_total)
     else:
         def sweep(ed, values, row, sub_act=None):
-            scal = jnp.stack([t0_d[row], tc_d[row],
-                              row * c]).astype(jnp.int32)
-            if masked:
-                scal = jnp.concatenate([scal, sub_act.astype(jnp.int32)])
-                operands = (ed.src, ed.dstl, ed.w, ed.valid, ed.cov,
-                            values, ed.aux)
-            else:
-                operands = (ed.src, ed.dstl, ed.w, ed.valid,
-                            values, ed.aux)
-            return call(scal, operands, 1).reshape(c)
+            base = row * c
+            old = lax.dynamic_slice(values, (base,), (c,))
+            agg = block_agg(ed, values, row, sub_act)
+            return program.apply(old, agg.reshape(c), n_total)
 
     _cache_put(key, sweep)
     return sweep

@@ -40,8 +40,9 @@ def edge_block_max(msg: jnp.ndarray, dst: jnp.ndarray, block_size: int,
 
 def make_block_sweep(program, store, block_size: int, n_total: int, *,
                      subblocks: int = 1, lanes: bool = False):
-    """Build the fused per-block sweep (gather→edge_map→combine→apply in
-    one pallas_call) over ``store``'s tile geometry. See
+    """Build the per-block sweep over ``store``'s tile geometry: gather and
+    ``edge_map`` in XLA, the segmented combine in one ``pallas_call`` per
+    chunk of tile rows, ``apply`` in XLA. See
     :mod:`repro.kernels.block_sweep`."""
     return _bs.make_block_sweep(
         program, store.tile_start, store.tile_cnt,

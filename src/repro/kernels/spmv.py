@@ -1,6 +1,6 @@
 """Pallas TPU kernel: edge-block segment-sum (the engine's hot spot).
 
-TPU adaptation of the paper's cache-block processing (DESIGN.md §2): a
+TPU adaptation of the paper's cache-block processing: a
 partition's edge slice is streamed HBM->VMEM in tiles of ``tile_e`` edges;
 the scatter-style segment reduction is re-expressed as a one-hot matmul so
 it runs on the MXU (systolic array) instead of a serial scatter unit:
@@ -34,7 +34,10 @@ def _kernel(msg_ref, dst_ref, out_ref, *, tile_e: int, block_c: int):
     # one-hot on the MXU contraction dim: (tile_e, block_c)
     cols = jax.lax.broadcasted_iota(jnp.int32, (tile_e, block_c), 1)
     onehot = (dst.reshape(tile_e, 1) == cols).astype(jnp.float32)
-    out_ref[...] += jnp.dot(msg, onehot,
+    # HIGHEST: the one-hot is exact in bf16 and the message splits into
+    # three bf16 terms, so the products are exact (DEFAULT would round
+    # each message to bf16 on the MXU)
+    out_ref[...] += jnp.dot(msg, onehot, precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
 

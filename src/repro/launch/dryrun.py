@@ -259,7 +259,6 @@ def lower_graph_cell(mesh, mesh_name: str, n: int = 2_000_000,
     """Dry-run the distributed structure-aware sweep (hot path) at pod scale:
     vertex state replicated, blocks round-robin on the data axis, psum/pmax
     reconciliation — storage passed as abstract args (no allocation)."""
-    from jax.experimental.shard_map import shard_map
 
     from repro.core.algorithms import pagerank
     from repro.core.engine import _combine_local
@@ -301,10 +300,10 @@ def lower_graph_cell(mesh, mesh_name: str, n: int = 2_000_000,
         psd_out = jax.lax.pmax(psd_l, "data")
         return values_out, psd_out
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         device_run, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(), P("data"), P("data")),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
 
     sds = jax.ShapeDtypeStruct
     args = (

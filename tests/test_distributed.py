@@ -42,6 +42,22 @@ def test_distributed_graph_engine_parity():
     """)
 
 
+def test_distributed_hot_dispatch_spans_mesh():
+    run_sub("""
+        import numpy as np
+        from repro.core import algorithms as A, graph as G
+        from repro.core.distributed import DistributedEngine
+        from repro.core.engine import EngineConfig
+        g = G.powerlaw_graph(3000, 6, seed=3)
+        dist = DistributedEngine(g, A.cc(), EngineConfig(block_size=256))
+        compiled = dist.compiled_hot_dispatch()
+        rows = compiled.input_shardings[0][3]
+        assert len(rows.device_set) == 4, rows
+        assert "all-reduce" in compiled.as_text()
+        print('SPREAD OK')
+    """, devices=4)
+
+
 def test_sharded_train_step_matches_single_device():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
@@ -115,7 +131,6 @@ def test_elastic_reshard_checkpoint():
 def test_ef_compressed_psum_in_shard_map():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.optim import ef_compress_psum
 
@@ -124,8 +139,8 @@ def test_ef_compressed_psum_in_shard_map():
             return ef_compress_psum(g, r, 'pod')
         g = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16) / 100.
         r = jnp.zeros((8, 16))
-        sm = shard_map(f, mesh=mesh, in_specs=(P('pod'), P('pod')),
-                       out_specs=(P('pod'), P('pod')), check_rep=False)
+        sm = jax.shard_map(f, mesh=mesh, in_specs=(P('pod'), P('pod')),
+                           out_specs=(P('pod'), P('pod')), check_vma=False)
         out, resid = jax.jit(sm)(g, r)
         want = jnp.broadcast_to(g.mean(0, keepdims=True), g.shape)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
