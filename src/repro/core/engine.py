@@ -115,8 +115,10 @@ from repro.analysis.contracts import one_executable_per
 from repro.core import state as state_lib
 from repro.core.algorithms import LaneProgram, VertexProgram
 from repro.core.graph import Graph, symmetrize
-from repro.core.metrics import COUNTER_FIELDS, Metrics, Timer, \
+from repro.core.metrics import COUNTER_FIELDS, HostSyncs, Metrics, Timer, \
     block_io_bytes
+from repro.obs import scopes as obs_scopes
+from repro.obs.scopes import gather
 from repro.obs import trace as obs_trace
 from repro.core.partition import (EdgeStorage, PartitionPlan, TiledStorage,
                                   build_plan)
@@ -444,13 +446,17 @@ def make_tiled_processor(program: VertexProgram, store: TiledStorage,
 
             def tile_compute(t, agg):
                 r = t0 + t
-                e_src = ed.src[r]
-                msg = program.edge_map(values[e_src], ed.aux[e_src],
-                                       ed.w[r])
-                msg = jnp.where(ed.valid[r], msg, program.identity)
-                return merge(agg,
-                             _combine_local(program, msg, ed.dstl[r], c,
-                                            use_pallas))
+                e_src = gather("sweep_gather_rows", ed.src, r)
+                msg = program.edge_map(
+                    gather("sweep_gather_values", values, e_src),
+                    gather("sweep_gather_aux", ed.aux, e_src),
+                    gather("sweep_gather_rows", ed.w, r))
+                msg = jnp.where(gather("sweep_gather_rows", ed.valid, r),
+                                msg, program.identity)
+                dstl = gather("sweep_gather_rows", ed.dstl, r)
+                with jax.named_scope("sweep_fold"):
+                    return merge(agg, _combine_local(program, msg, dstl, c,
+                                                     use_pallas))
 
             if sub_act is None:
                 tile_body = tile_compute
@@ -470,7 +476,8 @@ def make_tiled_processor(program: VertexProgram, store: TiledStorage,
             agg = lax.fori_loop(0, tile_cnt[row], tile_body, agg0)
             base = row * c
             old = lax.dynamic_slice(values, (base,), (c,))
-            new = program.apply(old, agg, n_total)
+            with jax.named_scope("sweep_apply"):
+                new = program.apply(old, agg, n_total)
         vmask = (base + jnp.arange(c)) < n_live
         if sub_act is None:
             new = jnp.where(vmask, new, old)
@@ -789,19 +796,22 @@ class StructureAwareEngine:
             that receive edges from the moving block re-arm. Calm then
             advances per sub-block. 1-D state traces to the exact flat
             path (the retire/re-arm unit test drives it directly)."""
-            d = jnp.where(dmax > eps, dmax, 0.0)
-            if psd.ndim == 2:
-                dblk = d.max(axis=1)
-                if coupling.ndim == 3:  # (P, P, S): sub-resolved bump
-                    bump = jnp.max(dblk[:, None, None] * coupling, axis=0)
-                else:  # S = 1 keeps the flat (P, P) coupling: exact old path
-                    bump = jnp.max(dblk[:, None] * coupling, axis=0)[:, None]
-                psd = jnp.maximum(psd, jnp.minimum(bump, 1e29))
-            else:
-                bump = jnp.max(d[:, None] * coupling, axis=0)
-                psd = jnp.maximum(psd, jnp.minimum(bump, 1e29))
-            calm = jnp.where(psd < floor, calm + 1, 0).astype(jnp.int32)
-            return psd, jnp.zeros_like(dmax), calm
+            with jax.named_scope("post"):
+                d = jnp.where(dmax > eps, dmax, 0.0)
+                if psd.ndim == 2:
+                    dblk = d.max(axis=1)
+                    if coupling.ndim == 3:  # (P, P, S): sub-resolved bump
+                        bump = jnp.max(dblk[:, None, None] * coupling,
+                                       axis=0)
+                    else:  # S = 1 keeps the flat (P, P) coupling
+                        bump = jnp.max(dblk[:, None] * coupling,
+                                       axis=0)[:, None]
+                    psd = jnp.maximum(psd, jnp.minimum(bump, 1e29))
+                else:
+                    bump = jnp.max(d[:, None] * coupling, axis=0)
+                    psd = jnp.maximum(psd, jnp.minimum(bump, 1e29))
+                calm = jnp.where(psd < floor, calm + 1, 0).astype(jnp.int32)
+                return psd, jnp.zeros_like(dmax), calm
         return post
 
     def _psd_floor(self) -> float:
@@ -1062,8 +1072,10 @@ class StructureAwareEngine:
                     ed, values, row, depths[i], sub_act)
                 return write_one(values, psd, dmax, base, new, psd_val,
                                  dmax_val, gids[row], ok[i], sub_act)
-            return lax.fori_loop(0, width, body, (values, psd, dmax))
+            with jax.named_scope("sweep_hot"):
+                return lax.fori_loop(0, width, body, (values, psd, dmax))
 
+        @jax.named_scope("sweep_cold")
         def cold_sweep(ed, values, psd, dmax, rows, ok):
             if subblocks == 1:
                 bases, news, psd_vals, dmax_vals = jax.vmap(
@@ -1184,22 +1196,25 @@ class StructureAwareEngine:
 
         def superstep(it, i2, ed, coupling, values, psd, dmax, calm, counts,
                       hslots, sbacc, is_hot):
-            hot_rows, hot_ok, cold_rows, cold_ok = select(it, i2, psd,
-                                                          is_hot)
+            with jax.named_scope("select"):
+                hot_rows, hot_ok, cold_rows, cold_ok = select(it, i2, psd,
+                                                              is_hot)
             # sub-dispatch accounting from the PRE-sweep psd — identical to
             # the sub_act masks the sweeps derive (rows are distinct within
             # a superstep; see _sweeps). At S = 1 every ok block counts 1,
             # so sbacc == block loads and the mean dispatch is exactly 1.0.
-            live = (psd >= floor).sum(axis=-1).astype(jnp.int32)
-            sbacc = sbacc + jnp.where(hot_ok, live[hot_rows], 0).sum() \
-                + jnp.where(cold_ok, live[cold_rows], 0).sum()
+            with jax.named_scope("account"):
+                live = (psd >= floor).sum(axis=-1).astype(jnp.int32)
+                sbacc = sbacc + jnp.where(hot_ok, live[hot_rows], 0).sum() \
+                    + jnp.where(cold_ok, live[cold_rows], 0).sum()
             values, psd, dmax = hot_sweep(ed, values, psd, dmax, hot_rows,
                                           hot_ok)
             values, psd, dmax = cold_sweep(ed, values, psd, dmax, cold_rows,
                                            cold_ok)
-            counts = counts.at[hot_rows].add(hot_ok.astype(jnp.int32))
-            counts = counts.at[cold_rows].add(cold_ok.astype(jnp.int32))
-            hslots = hslots + hot_ok.astype(jnp.int32)  # depth-hist feed
+            with jax.named_scope("account"):
+                counts = counts.at[hot_rows].add(hot_ok.astype(jnp.int32))
+                counts = counts.at[cold_rows].add(cold_ok.astype(jnp.int32))
+                hslots = hslots + hot_ok.astype(jnp.int32)  # depth hist
             # staleness propagation + calm/retire counter advance
             psd, dmax, calm = post(coupling, psd, dmax, calm)
             scheduled = hot_ok.any() | cold_ok.any()
@@ -1218,7 +1233,8 @@ class StructureAwareEngine:
                  scheduled) = superstep(it, i2, ed, coupling, values, psd,
                                         dmax, calm, counts, hslots, sbacc,
                                         is_hot)
-                conv = state_lib.converged_device(psd, t2)
+                with jax.named_scope("converge"):
+                    conv = state_lib.converged_device(psd, t2)
                 # empty schedule: no iteration happened (host parity: the
                 # reference loop breaks before processing)
                 it = it + jnp.where(scheduled, 1, 0).astype(it.dtype)
@@ -1226,13 +1242,16 @@ class StructureAwareEngine:
                 return (it, values, psd, dmax, calm, counts, hslots, sbacc,
                         done)
 
-            (it, values, psd, dmax, calm, counts, hslots, sbacc,
-             _) = lax.while_loop(
-                cond, body,
-                (it0, values, psd, dmax, calm, counts, hslots, sbacc,
-                 jnp.bool_(False)))
+            with jax.named_scope("chunk_loop"):
+                (it, values, psd, dmax, calm, counts, hslots, sbacc,
+                 _) = lax.while_loop(
+                    cond, body,
+                    (it0, values, psd, dmax, calm, counts, hslots, sbacc,
+                     jnp.bool_(False)))
+            with jax.named_scope("converge"):
+                conv = state_lib.converged_device(psd, t2)
             return (it, values, psd, dmax, calm, counts, hslots, sbacc,
-                    state_lib.converged_device(psd, t2))
+                    conv)
 
         if trace_cap is None:
             fn = jax.jit(chunk, donate_argnums=(2, 3, 4, 5, 6, 7, 8))
@@ -1251,8 +1270,9 @@ class StructureAwareEngine:
             # the select inside ``superstep`` (identical inputs), so XLA
             # CSEs it away — and even uncached it could only duplicate
             # work, never change a decision
-            hot_rows, hot_ok, cold_rows, cold_ok = select(it, i2, psd,
-                                                          is_hot)
+            with jax.named_scope("select"):
+                hot_rows, hot_ok, cold_rows, cold_ok = select(it, i2, psd,
+                                                              is_hot)
             (values, psd, dmax, calm, counts, hslots, sbacc,
              scheduled) = superstep(it, i2, ed, coupling, values, psd,
                                     dmax, calm, counts, hslots, sbacc,
@@ -1298,19 +1318,23 @@ class StructureAwareEngine:
                  hist_f, scheduled) = superstep_traced(
                     it, it0, i2, ed, coupling, values, psd, dmax, calm,
                     counts, hslots, sbacc, hist_i, hist_f, is_hot, acct)
-                conv = state_lib.converged_device(psd, t2)
+                with jax.named_scope("converge"):
+                    conv = state_lib.converged_device(psd, t2)
                 it = it + jnp.where(scheduled, 1, 0).astype(it.dtype)
                 done = conv | jnp.logical_not(scheduled)
                 return (it, values, psd, dmax, calm, counts, hslots,
                         sbacc, hist_i, hist_f, done)
 
-            (it, values, psd, dmax, calm, counts, hslots, sbacc, hist_i,
-             hist_f, _) = lax.while_loop(
-                cond, body,
-                (it0, values, psd, dmax, calm, counts, hslots, sbacc,
-                 hist_i, hist_f, jnp.bool_(False)))
+            with jax.named_scope("chunk_loop"):
+                (it, values, psd, dmax, calm, counts, hslots, sbacc, hist_i,
+                 hist_f, _) = lax.while_loop(
+                    cond, body,
+                    (it0, values, psd, dmax, calm, counts, hslots, sbacc,
+                     hist_i, hist_f, jnp.bool_(False)))
+            with jax.named_scope("converge"):
+                conv = state_lib.converged_device(psd, t2)
             return (it, values, psd, dmax, calm, counts, hslots, sbacc,
-                    hist_i, hist_f, state_lib.converged_device(psd, t2))
+                    hist_i, hist_f, conv)
 
         fn = jax.jit(chunk_traced,
                      donate_argnums=(2, 3, 4, 5, 6, 7, 8, 14, 15))
@@ -1342,13 +1366,24 @@ class StructureAwareEngine:
             self._get_chunk(wb)(*self._idle_chunk_args(wb))
         return list(self._ladder)
 
-    def compiled_chunk_text(self) -> str:
-        """The compiled fused chunk's program text at the widest dispatch
-        bucket — what a caller inspects to see which kernels the backend
-        runs (``tpu_custom_call`` for Pallas)."""
-        wb = self._ladder[0]
+    def compiled_chunk_text(self, width: int | None = None) -> str:
+        """The compiled fused chunk's program text at dispatch bucket
+        ``width`` (default the widest) — what a caller inspects to see
+        which kernels the backend runs (``tpu_custom_call`` for Pallas)."""
+        wb = self._ladder[0] if width is None else width
         return self._get_chunk(wb).lower(
             *self._idle_chunk_args(wb)).compile().as_text()
+
+    def op_scopes(self) -> dict[str, str]:
+        """Device operation -> named scope of the superstep it belongs to
+        (:data:`repro.obs.scopes.SCOPES`, or ``unscoped``), over the
+        compiled fused chunk of every dispatch bucket, keyed by
+        instruction name and result shape as a profiler trace prints them
+        (:func:`repro.obs.scopes.op_key`). Compiles each bucket again, so
+        it belongs after any timed work."""
+        return obs_scopes.merge(
+            obs_scopes.op_scopes(self.compiled_chunk_text(wb))
+            for wb in self._ladder)
 
     # -- main loop ----------------------------------------------------------
     def run(self, max_iterations: int | None = None,
@@ -1431,11 +1466,12 @@ class StructureAwareEngine:
         cfg, p = self.config, self.plan
         max_it = max_iterations or cfg.max_iterations
 
+        syncs = HostSyncs()
         values, psd, rep, calm_host, i2 = self._start_state(warm)
         calm = jnp.asarray(calm_host)
         # host-side decisions (repartition, dispatch bucket, history) are
         # block-granular: fold the (P, S) sub-block psd to block priority
-        psd_sub_host = np.asarray(psd)
+        psd_sub_host = syncs.read(psd)
         psd_host = state_lib.fold_subblock_psd(psd_sub_host)
         active = self._active_count(calm_host)
         dmax = jnp.zeros((p.num_blocks, cfg.subblocks), jnp.float32)
@@ -1515,18 +1551,28 @@ class StructureAwareEngine:
                             jnp.zeros(wb, jnp.int32), jnp.int32(0),
                             jnp.int32(it), jnp.int32(it_end),
                             jnp.asarray(rep.is_hot), jnp.int32(i2))
-                    # the chunk's single host sync point
-                    it_new = int(it_dev)
-                    psd_sub_host = np.asarray(psd)
+                    # the chunk's host sync: every blocking read of the
+                    # chunk's results, one transfer each (the history
+                    # buffers ride the same sync, so per-superstep
+                    # resolution adds no round-trip of its own)
+                    with obs_trace.span("sync", cat="engine"):
+                        it_new = int(syncs.read(it_dev))
+                        psd_sub_host = syncs.read(psd)
+                        calm_host = syncs.read(calm)
+                        counts_host = np.asarray(syncs.read(counts),
+                                                 dtype=np.int64)
+                        sb_chunk = int(syncs.read(sbacc))
+                        hslots_host = syncs.read(hslots)
+                        converged = bool(syncs.read(conv))
+                        if trace:
+                            hi = syncs.read(hist_i)[:it_new - it]
+                            hf = syncs.read(hist_f)[:it_new - it]
+                    csp.set(it_end=it_new)
+                # host work from the end of the reads to the next chunk's
+                # dispatch: counters, history, repartition, bucket pick
+                with obs_trace.span("boundary", cat="engine"):
                     psd_host = state_lib.fold_subblock_psd(psd_sub_host)
-                    calm_host = np.asarray(calm)
-                    counts_host = np.asarray(counts, dtype=np.int64)
                     if trace:
-                        # history buffers flush in the SAME sync — the
-                        # per-superstep resolution is free of extra host
-                        # round-trips
-                        hi = np.asarray(hist_i)[:it_new - it]
-                        hf = np.asarray(hist_f)[:it_new - it]
                         rows = []
                         for k in range(it_new - it):
                             row = {"superstep": it + k, "width": wb}
@@ -1536,62 +1582,66 @@ class StructureAwareEngine:
                                            (float(v) for v in hf[k])))
                             rows.append(row)
                         timeline.extend(rows)
-                    csp.set(it_end=it_new)
-                if rec is not None and trace and rows:
-                    rec.counter_rows("superstep", rows, csp.t0, csp.t1)
-                delta = counts_host @ acct
-                metrics.absorb_counters(delta)
-                sb_total += int(sbacc)
-                span = it_new - it
-                width_iters += wb * span
-                for d, cnt in zip(self._inner_depths(wb).tolist(),
-                                  np.asarray(hslots).tolist()):
-                    if cnt:
-                        depth_hist[int(d)] = depth_hist.get(int(d), 0) + \
-                            int(cnt)
-                history.append({
-                    "iteration": max(it_new - 1, 0),
-                    "span": span,  # iterations covered by this entry
-                    "psd_sum": float(psd_host[psd_host <
-                                              state_lib.UNSEEN].sum()),
-                    "unseen": int((psd_host >= state_lib.UNSEEN).sum()),
-                    "hot_blocks": int(rep.is_hot.sum()),
-                    "scheduled": int(delta[2]),  # block loads
-                    "width": wb,
-                    "retired": p.num_blocks - self._active_count(calm_host),
-                })
-                if bool(conv):
-                    metrics.converged = True
+                        if rec is not None and rows:
+                            rec.counter_rows("superstep", rows, csp.t0,
+                                             csp.t1)
+                    delta = counts_host @ acct
+                    metrics.absorb_counters(delta)
+                    sb_total += sb_chunk
+                    span = it_new - it
+                    width_iters += wb * span
+                    for d, cnt in zip(self._inner_depths(wb).tolist(),
+                                      hslots_host.tolist()):
+                        if cnt:
+                            depth_hist[int(d)] = depth_hist.get(int(d),
+                                                                0) + int(cnt)
+                    history.append({
+                        "iteration": max(it_new - 1, 0),
+                        "span": span,  # iterations covered by this entry
+                        "psd_sum": float(psd_host[psd_host <
+                                                  state_lib.UNSEEN].sum()),
+                        "unseen": int((psd_host >= state_lib.UNSEEN).sum()),
+                        "hot_blocks": int(rep.is_hot.sum()),
+                        "scheduled": int(delta[2]),  # block loads
+                        "width": wb,
+                        "retired": p.num_blocks
+                        - self._active_count(calm_host),
+                    })
+                    if converged:
+                        metrics.converged = True
+                        it = it_new
+                        break
+                    if it_new == it:  # schedule went empty: nothing left
+                        break
                     it = it_new
-                    break
-                if it_new == it:  # schedule went empty: nothing left to do
-                    break
-                it = it_new
-                # a no-op until it - 1 reaches the boundary, so the paged
-                # per-superstep calls fire on exactly the resident cadence
-                with obs_trace.span("repartition", cat="engine",
-                                    iteration=it - 1) as rsp:
-                    fired = rep.maybe_repartition(it - 1, psd_host,
-                                                  cfg.hot_ratio)
-                    rsp.set(fired=fired)
-                # next chunk's bucket follows the live active set, exactly
-                # like the host loop's boundary retarget. In paged mode the
-                # bucket changes ONLY at fired boundaries (the resident
-                # path's chunks always end at boundaries, so this is the
-                # same retarget cadence — a per-superstep retarget would
-                # change the cold quota and fork the trajectory).
-                active = self._active_count(calm_host)
-                if spill is None or fired:
-                    wb = self._pick_width(active, psd_host)
-                if spill is not None and fired:
-                    # activity-directed prefetch at the boundary: stage the
-                    # predicted next-superstep demand plus the hottest
-                    # non-resident blocks, swapping out retired ones only
-                    pred.width = wb
-                    nsel = pred.select(it, psd_sub_host, rep.is_hot)
-                    spill.prefetch_boundary(
-                        ooc_policy.demand_blocks(nsel, self.pad_id),
-                        psd_host, calm_host)
+                    # a no-op until it - 1 reaches the boundary, so the
+                    # paged per-superstep calls fire on exactly the
+                    # resident cadence
+                    with obs_trace.span("repartition", cat="engine",
+                                        iteration=it - 1) as rsp:
+                        fired = rep.maybe_repartition(it - 1, psd_host,
+                                                      cfg.hot_ratio)
+                        rsp.set(fired=fired)
+                    # next chunk's bucket follows the live active set,
+                    # exactly like the host loop's boundary retarget. In
+                    # paged mode the bucket changes ONLY at fired
+                    # boundaries (the resident path's chunks always end at
+                    # boundaries, so this is the same retarget cadence — a
+                    # per-superstep retarget would change the cold quota
+                    # and fork the trajectory).
+                    active = self._active_count(calm_host)
+                    if spill is None or fired:
+                        wb = self._pick_width(active, psd_host)
+                    if spill is not None and fired:
+                        # activity-directed prefetch at the boundary: stage
+                        # the predicted next-superstep demand plus the
+                        # hottest non-resident blocks, swapping out retired
+                        # ones only
+                        pred.width = wb
+                        nsel = pred.select(it, psd_sub_host, rep.is_hot)
+                        spill.prefetch_boundary(
+                            ooc_policy.demand_blocks(nsel, self.pad_id),
+                            psd_host, calm_host)
         metrics.iterations = it
         metrics.wall_time_s = t.elapsed
         metrics.mean_dispatch_width = width_iters / max(it, 1)
@@ -1604,7 +1654,8 @@ class StructureAwareEngine:
             spill.flush_metrics(metrics)
         self.last_psd = psd_sub_host
         self.last_calm = np.asarray(calm_host)
-        out = np.asarray(values)[self.plan.inv]  # back to original ids
+        out = syncs.read(values)[self.plan.inv]  # back to original ids
+        metrics.host_syncs = syncs.count
         return RunResult(values=out, metrics=metrics, history=history,
                          timeline=timeline)
 
@@ -1614,11 +1665,12 @@ class StructureAwareEngine:
         cfg, p = self.config, self.plan
         max_it = max_iterations or cfg.max_iterations
 
+        syncs = HostSyncs()
         values, psd, rep, calm_host, i2 = self._start_state(warm)
         # psd_sub is the raw (P, S) sub-block state (sb-dispatch accounting
         # + the scheduler folds it internally); psd_host its block fold for
         # the host-side block-granular decisions
-        psd_sub = np.asarray(psd)
+        psd_sub = syncs.read(psd)
         psd_host = state_lib.fold_subblock_psd(psd_sub)
         sched = Scheduler(width=self._pick_width(
                               self._active_count(calm_host), psd_host),
@@ -1653,7 +1705,7 @@ class StructureAwareEngine:
                     # (block 0 — the host dispatch's row padding — is
                     # pinned resident by the store)
                     spill.admit(ooc_policy.demand_blocks(sel, self.pad_id),
-                                psd_host, np.asarray(calm))
+                                psd_host, syncs.read(calm))
                 processed = np.concatenate([sel.hot_ids, sel.cold_ids])
                 w_used = sched.width  # this iteration's bucket (the
                 # boundary retarget below may change it before history)
@@ -1675,7 +1727,7 @@ class StructureAwareEngine:
                 # also advances the calm/retire counters.
                 psd, dmax, calm = self._post(self._coupling_dev, psd, dmax,
                                              calm)
-                psd_sub = np.asarray(psd)
+                psd_sub = syncs.read(psd)
                 psd_host = state_lib.fold_subblock_psd(psd_sub)
                 with obs_trace.span("repartition", cat="engine",
                                     iteration=it) as rsp:
@@ -1685,7 +1737,7 @@ class StructureAwareEngine:
                 if fired and cfg.adaptive:
                     # boundary retarget: same cadence as the fused path's
                     # per-chunk bucket pick
-                    calm_host = np.asarray(calm)
+                    calm_host = syncs.read(calm)
                     sched.width = self._pick_width(
                         self._active_count(calm_host), psd_host)
                 if fired and spill is not None:
@@ -1694,7 +1746,7 @@ class StructureAwareEngine:
                     nsel = sched.select(it + 1, psd_sub, rep.is_hot)
                     spill.prefetch_boundary(
                         ooc_policy.demand_blocks(nsel, self.pad_id),
-                        psd_host, np.asarray(calm))
+                        psd_host, syncs.read(calm))
                 history.append({
                     "iteration": it,
                     "psd_sum": float(psd_host[psd_host <
@@ -1714,7 +1766,7 @@ class StructureAwareEngine:
                     row = {"superstep": it, "width": w_used,
                            "hot_loads": int(sel.hot_ids.size),
                            "retired": p.num_blocks
-                           - self._active_count(np.asarray(calm)),
+                           - self._active_count(syncs.read(calm)),
                            "unseen": int((~finite).sum()),
                            "psd_sum": float(
                                psd_host[finite].astype(np.float32).sum()),
@@ -1728,7 +1780,7 @@ class StructureAwareEngine:
                 if state_lib.converged(psd_sub, cfg.t2):
                     metrics.converged = True
                     break
-        calm_host = np.asarray(calm)
+        calm_host = syncs.read(calm)
         depths = self._inner_depths(cfg.width)
         for d, cnt in zip(depths.tolist(), hslots.tolist()):
             if cnt:
@@ -1745,7 +1797,8 @@ class StructureAwareEngine:
             spill.flush_metrics(metrics)
         self.last_psd = psd_sub
         self.last_calm = calm_host
-        out = np.asarray(values)[self.plan.inv]  # back to original ids
+        out = syncs.read(values)[self.plan.inv]  # back to original ids
+        metrics.host_syncs = syncs.count
         return RunResult(values=out, metrics=metrics, history=history,
                          timeline=timeline)
 

@@ -10,13 +10,33 @@ from __future__ import annotations
 import dataclasses
 import time
 
+import numpy as np
+
 
 # Order of the accounting vector the fused engine flushes at repartition
 # boundaries: the device accumulates exact per-block schedule counts, the
 # host expands them through a per-block [vertices, edges, loads, bytes]
-# table into this layout.
+# table into this layout. ``host_syncs`` stays out: a traced run reads two
+# more buffers per chunk, and the traced/untraced parity compares these.
 COUNTER_FIELDS = ("updates", "edges_processed", "block_loads",
                   "bytes_loaded")
+
+_fetch = np.asarray  # the blocking device->host copy behind HostSyncs
+
+
+class HostSyncs:
+    """Counts the blocking device->host reads of one run, or of one
+    streaming batch's apply: every such read goes through :meth:`read`,
+    which waits for the device value and returns it as a host array."""
+
+    __slots__ = ("count",)
+
+    def __init__(self):
+        self.count = 0
+
+    def read(self, x) -> np.ndarray:
+        self.count += 1
+        return _fetch(x)
 
 
 def _with_properties(m) -> dict:
@@ -69,6 +89,7 @@ class Metrics:
     prefetch_hits: int = 0  # scheduled-block demands already resident
     prefetch_misses: int = 0  # demand fetches the prefetcher missed
     bytes_fetched: int = 0  # tile-row bytes scattered back on demand/prefetch
+    host_syncs: int = 0  # blocking device->host reads (see HostSyncs)
 
     @property
     def prefetch_hit_rate(self) -> float:
@@ -140,6 +161,7 @@ class StreamMetrics:
     prefetch_hits: int = 0
     prefetch_misses: int = 0
     bytes_fetched: int = 0
+    host_syncs: int = 0  # blocking device->host reads across batches
 
     @property
     def dirty_frac(self) -> float:

@@ -43,6 +43,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.analysis.contracts import one_executable_per
+from repro.obs.scopes import gather
 
 # one sweep callable per (program, tile geometry, mode): the engines build
 # their processors once per epoch, and repeated builds (prewarm, contract
@@ -269,23 +270,28 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
             r = jnp.minimum(t0 + j, n_tiles - 1)
             live = j < cnt
             if masked:
-                live = live & (ed.cov[r] & sub_act).any(axis=1)
+                live = live & (gather("sweep_gather_rows", ed.cov, r)
+                               & sub_act).any(axis=1)
 
             def accumulate(agg):
-                ok = ed.valid[r] & live[:, None]
-                src = ed.src[r]
+                ok = gather("sweep_gather_rows", ed.valid, r) & live[:, None]
+                src = gather("sweep_gather_rows", ed.src, r)
                 if lanes:
-                    flat = src.reshape(-1)
-                    msg = program.edge_map(values[flat], ed.aux[flat],
-                                           ed.w[r].reshape(-1))
+                    src = src.reshape(-1)
+                vals = gather("sweep_gather_values", values, src)
+                aux = gather("sweep_gather_aux", ed.aux, src)
+                w = gather("sweep_gather_rows", ed.w, r)
+                if lanes:
+                    msg = program.edge_map(vals, aux, w.reshape(-1))
                     msg = jnp.where(ok.reshape(-1, 1), msg, ident)
                     msg = msg.reshape(k, tile, nl).transpose(0, 2, 1)
                 else:
-                    msg = program.edge_map(values[src], ed.aux[src],
-                                           ed.w[r])
+                    msg = program.edge_map(vals, aux, w)
                     msg = jnp.where(ok, msg, ident)[:, None, :]
-                return combine(agg, msg.astype(jnp.float32),
-                               ed.dstl[r][:, None, :])
+                msg = msg.astype(jnp.float32)
+                dstl = gather("sweep_gather_rows", ed.dstl, r)[:, None, :]
+                with jax.named_scope("sweep_fold"):
+                    return combine(agg, msg, dstl)
 
             if not masked:
                 return accumulate(agg)
@@ -302,13 +308,15 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
             old = lax.dynamic_slice(values, (base, 0), (c, nl))
             vc = lax.dynamic_slice(vconst, (base, 0), (c, nl))
             agg = block_agg(ed, values, row, sub_act)
-            return program.apply(old, agg.T, vc, n_total)
+            with jax.named_scope("sweep_apply"):
+                return program.apply(old, agg.T, vc, n_total)
     else:
         def sweep(ed, values, row, sub_act=None):
             base = row * c
             old = lax.dynamic_slice(values, (base,), (c,))
             agg = block_agg(ed, values, row, sub_act)
-            return program.apply(old, agg.reshape(c), n_total)
+            with jax.named_scope("sweep_apply"):
+                return program.apply(old, agg.reshape(c), n_total)
 
     _cache_put(key, sweep)
     return sweep

@@ -7,10 +7,15 @@ Two halves:
     dispatch width, retirements, PSD stats) flushed at the existing
     repartition-boundary sync and surfaced as ``RunResult.timeline``;
   * host side — :class:`TraceRecorder` collects nested spans (``run``,
-    ``repartition``, ``ingest``, ``spill_evict``/``prefetch``,
+    ``chunk``, ``sync``, ``boundary``, ``repartition``, ``ingest``,
+    ``apply``, ``commit``, ``reconverge``, ``spill_evict``/``prefetch``,
     ``snapshot``, ``query_batch``) from engine/stream/serve/ooc into a
     ring buffer, exported as Chrome-trace/Perfetto JSON
     (:mod:`repro.obs.export`) and rendered by ``python -m repro.obs``.
+    Each span is also a profiler ``TraceAnnotation`` named
+    ``<cat>.<name>``, so a ``jax.profiler`` trace holds them on the
+    device's clock; :mod:`repro.obs.scopes` names the device operations
+    by the ``jax.named_scope`` of the superstep part they ran in.
 
 Typical capture::
 

@@ -4,8 +4,8 @@ Emits the Trace Event Format ``{"traceEvents": [...]}`` JSON object
 (the format chrome://tracing and https://ui.perfetto.dev load
 directly): spans as complete events (``"ph": "X"``, ``ts``/``dur`` in
 microseconds), per-superstep counters as counter events (``"ph": "C"``
-— Perfetto plots each ``args`` key as a series), instants as
-``"ph": "i"``, plus one metadata event naming the process.
+— Perfetto plots each ``args`` key as a series), plus one metadata
+event naming the process.
 
 :func:`validate` is the schema check the CI trace-smoke step (and the
 ``python -m repro.obs validate`` CLI) runs over exported payloads, so a
@@ -35,9 +35,6 @@ def to_chrome(recorder, meta: dict | None = None) -> dict:
                            "args": ev["args"]})
         elif ev["type"] == "counter":
             events.append({**base, "ph": "C", "args": ev["values"]})
-        elif ev["type"] == "instant":
-            events.append({**base, "ph": "i", "s": "t",
-                           "args": ev["args"]})
     return {"traceEvents": events, "displayTimeUnit": "ms",
             "otherData": {"dropped_events": recorder.dropped,
                           **(meta or {})}}

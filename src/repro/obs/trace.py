@@ -6,27 +6,32 @@ block retired, when a re-arm wave fired, which ingest stalled a serve
 batch), not just the end-of-run totals the ``Metrics`` classes carry.
 This module is the host half of the observability layer:
 
-  * :class:`TraceRecorder` — structured events (spans, instants, counter
-    rows) appended to a ``deque`` ring buffer; overflow drops the OLDEST
+  * :class:`TraceRecorder` — structured events (spans and counter rows)
+    appended to a ``deque`` ring buffer; overflow drops the OLDEST
     events and counts them (``dropped``), so a long-lived service can
     keep a recorder installed forever at bounded memory.
   * module-level ``install()`` / ``current()`` / ``recording()`` — the
-    engines look the recorder up per call; with none installed,
-    :func:`span` returns a shared no-op context whose cost is one global
-    read, which is what keeps the instrumented hot paths free when
-    tracing is off.
+    engines look the recorder up per call.
   * :func:`span` — nested-span context manager. The yielded handle
     carries ``t0``/``t1`` (seconds, relative to the recorder epoch) and
     ``set(**args)`` for results only known at exit (e.g. whether a
     repartition boundary actually fired).
 
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``<cat>.<name>`` (``engine.chunk``, ``stream.ingest``), entered whether
+or not a recorder is installed: under a profiler session the spans land
+on the profiler's host plane, on the same clock as the device's
+operations, so a device gap can be attributed to the host step it sits
+in. With no profiler session the annotation is a no-op; with no
+recorder installed either, a span costs that no-op and one global read.
+
 All clock reads live HERE, not at the instrumented call sites: the
 schedule-affecting modules (``ooc/store.py`` and friends) are under the
 RA004 no-clocks lint rule, and routing their spans through this module
 keeps them clock-free while still timestamping their events. Nothing in
-this module imports jax or touches device state — recording a span can
-never perturb a trajectory (bitwise parity with tracing on is
-property-tested in ``tests/test_obs.py``).
+this module touches device state (it imports only ``jax.profiler``) —
+recording a span can never perturb a trajectory (bitwise parity with
+tracing on is property-tested in ``tests/test_obs.py``).
 
 Timestamps are ``time.perf_counter()`` deltas (monotonic) against the
 recorder's construction epoch; the Chrome-trace exporter
@@ -37,6 +42,8 @@ from __future__ import annotations
 import time
 from collections import deque
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 DEFAULT_CAPACITY = 65536  # events kept before the ring starts dropping
 
@@ -71,21 +78,33 @@ class _NullSpan:
         pass
 
 
-class _NullContext:
-    """Reusable no-op context manager: one global read + one attribute
-    call is the whole cost of an un-recorded span."""
+class _Span:
+    """A span's context: the profiler annotation around the recorder's
+    span (``inner``), or around the shared no-op handle when no recorder
+    is installed."""
 
-    __slots__ = ()
+    __slots__ = ("annotation", "inner")
+
+    def __init__(self, annotation: TraceAnnotation, inner):
+        self.annotation = annotation
+        self.inner = inner
 
     def __enter__(self):
-        return _NULL_SPAN
+        self.annotation.__enter__()
+        if self.inner is None:
+            return _NULL_SPAN
+        return self.inner.__enter__()
 
     def __exit__(self, *exc):
+        try:
+            if self.inner is not None:
+                self.inner.__exit__(*exc)
+        finally:
+            self.annotation.__exit__(*exc)
         return False
 
 
 _NULL_SPAN = _NullSpan()
-_NULL_CONTEXT = _NullContext()
 
 
 class TraceRecorder:
@@ -93,7 +112,6 @@ class TraceRecorder:
 
     Event shapes (plain dicts, exporter-agnostic):
       ``{"type": "span", "name", "cat", "ts", "dur", "depth", "args"}``
-      ``{"type": "instant", "name", "cat", "ts", "args"}``
       ``{"type": "counter", "name", "cat", "ts", "values"}``
     ``ts``/``dur`` are seconds relative to the recorder epoch.
     """
@@ -127,10 +145,6 @@ class TraceRecorder:
                         "ts": h.t0, "dur": h.t1 - h.t0,
                         "depth": self._depth, "args": h.args})
 
-    def instant(self, name: str, cat: str = "", **args) -> None:
-        self._push({"type": "instant", "name": name, "cat": cat,
-                    "ts": self.now(), "args": dict(args)})
-
     def counter_rows(self, name: str, rows: list, t0: float, t1: float,
                      cat: str = "engine") -> None:
         """Emit one counter event per row, timestamps interpolated
@@ -156,8 +170,8 @@ _CURRENT: TraceRecorder | None = None
 
 
 def install(recorder: TraceRecorder) -> TraceRecorder:
-    """Make ``recorder`` the process-wide target of :func:`span` /
-    :func:`instant`. Returns it (chaining convenience)."""
+    """Make ``recorder`` the process-wide target of :func:`span`.
+    Returns it (chaining convenience)."""
     global _CURRENT
     _CURRENT = recorder
     return recorder
@@ -192,16 +206,9 @@ def recording(capacity: int = DEFAULT_CAPACITY):
 
 
 def span(name: str, cat: str = "", **args):
-    """Span against the installed recorder; a shared no-op context when
-    none is installed (the instrumented hot paths call this
-    unconditionally)."""
+    """Span against the installed recorder, annotated for the profiler as
+    ``<cat>.<name>``; with no recorder installed the handle is a shared
+    no-op (the instrumented hot paths call this unconditionally)."""
     rec = _CURRENT
-    if rec is None:
-        return _NULL_CONTEXT
-    return rec.span(name, cat, **args)
-
-
-def instant(name: str, cat: str = "", **args) -> None:
-    rec = _CURRENT
-    if rec is not None:
-        rec.instant(name, cat, **args)
+    return _Span(TraceAnnotation(f"{cat}.{name}" if cat else name),
+                 None if rec is None else rec.span(name, cat, **args))

@@ -62,7 +62,7 @@ from repro.core.engine import (EdgeData, EngineConfig, RunResult,
                                coupling_from_counts)
 from repro.core.schedule import adaptive_i2
 from repro.core.graph import Graph, edges_of, from_edges, symmetrize
-from repro.core.metrics import StreamMetrics, Timer
+from repro.core.metrics import HostSyncs, StreamMetrics, Timer
 from repro.obs import trace as obs_trace
 from repro.stream.apply import EdgeStore, MutableTiledState
 from repro.stream.delta import DeltaBatch
@@ -116,6 +116,10 @@ class StreamBatchReport:
     prefetch_hits: int = 0
     prefetch_misses: int = 0
     bytes_fetched: int = 0
+    # blocking device->host reads of the batch: its apply's and its warm
+    # reconvergence's (an overflow batch's engine construction is not
+    # counted)
+    host_syncs: int = 0
 
     @property
     def dirty_frac(self) -> float:
@@ -267,8 +271,9 @@ class StreamingEngine:
 
     # -- epoch management ----------------------------------------------------
     def _build_epoch(self, src: np.ndarray, dst: np.ndarray,
-                     w: np.ndarray) -> None:
-        """(Re)build engine + mutable mirrors from a base COO snapshot."""
+                     w: np.ndarray, syncs: HostSyncs | None = None) -> None:
+        """(Re)build engine + mutable mirrors from a base COO snapshot;
+        ``syncs`` counts its device reads (a rebuild inside a batch)."""
         g = from_edges(self.n, src, dst, w)
         self.engine = StructureAwareEngine(g, self.program, self.config)
         plan = self.engine.plan
@@ -284,7 +289,7 @@ class StreamingEngine:
         self.in_deg = plan.graph.in_deg.astype(np.int64)
         # block -> block internal edge counts (staleness coupling truth)
         self.W = self.engine.coupling_counts.copy()
-        self._aux = np.array(self.engine.aux)
+        self._aux = np.array((syncs or HostSyncs()).read(self.engine.aux))
         # every registered init carries @structure_independent
         # (repro.analysis.contracts — the normative statement), so one
         # epoch snapshot serves every delete-reset without rebuilding a
@@ -317,10 +322,10 @@ class StreamingEngine:
             z, coupling_from_counts(self.W[:1], self.program,
                                     eng.plan.block_size))
 
-    def _rebuild_epoch(self) -> None:
+    def _rebuild_epoch(self, syncs: HostSyncs) -> None:
         ps, pd, w = self.store.live_base()
         order = self.engine.plan.order
-        self._build_epoch(order[ps], order[pd], w)
+        self._build_epoch(order[ps], order[pd], w, syncs)
         self.metrics.plan_rebuilds += 1
 
     # -- public state --------------------------------------------------------
@@ -438,8 +443,9 @@ class StreamingEngine:
         empty = np.empty(0, dtype=np.int64)
         reset_blocks = empty
         reset_verts = empty  # permuted ids, for sub-block-granular arming
+        syncs = HostSyncs()  # the apply's blocking device reads
 
-        with Timer() as t_ing:
+        with obs_trace.span("apply", cat="stream"), Timer() as t_ing:
             # 1. mutate the base truth (deletes first, then inserts)
             killed = self.store.kill_pairs(inv[batch.del_src],
                                            inv[batch.del_dst])
@@ -607,7 +613,8 @@ class StreamingEngine:
                 # in-place maintenance. Everything is perturbed, so the
                 # warm run starts fully active (no calm seed, base i2).
                 appended = rebuilt = killed_blocks = 0
-                self._rebuild_epoch()
+                with obs_trace.span("commit", cat="stream"):
+                    self._rebuild_epoch(syncs)
                 eng = self.engine
                 plan = eng.plan
                 dirty = np.ones(plan.num_blocks, dtype=bool)
@@ -624,13 +631,15 @@ class StreamingEngine:
                 # into the resident (donated) buffers — O(touched), not
                 # O(m), host->device traffic
                 rows = self.tiles.pop_dirty_rows()
-                if rows.size:
-                    bytes_up += eng.update_edge_rows(
-                        rows, **self.tiles.rows2d(rows))
-                bytes_up += eng.update_aux(aux_changed, aux_vals)
-                if wrows.size:
-                    bytes_up += eng.update_coupling_rows(
-                        wrows, coupling_from_counts(self.W[wrows], prog, c))
+                with obs_trace.span("commit", cat="stream"):
+                    if rows.size:
+                        bytes_up += eng.update_edge_rows(
+                            rows, **self.tiles.rows2d(rows))
+                    bytes_up += eng.update_aux(aux_changed, aux_vals)
+                    if wrows.size:
+                        bytes_up += eng.update_coupling_rows(
+                            wrows, coupling_from_counts(self.W[wrows], prog,
+                                                        c))
                 eng.edge_counts = self.tiles.live.copy()
                 dirty = np.zeros(plan.num_blocks, dtype=bool)
                 for ids in (kill_set, rebuild_set, append_set, aux_dirty,
@@ -752,7 +761,8 @@ class StreamingEngine:
             bytes_spilled=res.metrics.bytes_spilled if res else 0,
             prefetch_hits=res.metrics.prefetch_hits if res else 0,
             prefetch_misses=res.metrics.prefetch_misses if res else 0,
-            bytes_fetched=res.metrics.bytes_fetched if res else 0)
+            bytes_fetched=res.metrics.bytes_fetched if res else 0,
+            host_syncs=syncs.count + (res.metrics.host_syncs if res else 0))
         self._absorb(report)
         return report
 
@@ -852,5 +862,6 @@ class StreamingEngine:
         m.prefetch_hits += r.prefetch_hits
         m.prefetch_misses += r.prefetch_misses
         m.bytes_fetched += r.bytes_fetched
+        m.host_syncs += r.host_syncs
         for d, cnt in r.inner_depth_hist.items():
             m.inner_depth_hist[d] = m.inner_depth_hist.get(d, 0) + cnt

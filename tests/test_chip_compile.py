@@ -107,3 +107,33 @@ def test_block_sweep_compiles_for_v5e(variant, form, one_chip, no_cache):
         ed, vals, vals, _spec(row_shape, jnp.int32, one_chip),
         _spec(row_shape + (subblocks,), jnp.bool_, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_chunk_scopes_for_v5e(one_chip, no_cache, monkeypatch):
+    """The engine's fused chunk compiled for v5e: every named scope of
+    the superstep reaches the compiled program, and every operation
+    that does work maps to one of them (``op_scopes``' reading)."""
+    from repro.core import graph as G
+    from repro.core.engine import EngineConfig, StructureAwareEngine
+    from repro.kernels import ops as kops
+    from repro.obs import scopes
+
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    g = G.powerlaw_graph(4096, avg_deg=8, seed=0, weighted=True)
+    eng = StructureAwareEngine(g, A.pagerank(), EngineConfig(
+        t2=1e-6, use_pallas=True))
+    wb = eng._ladder[0]  # the widest dispatch bucket
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    args = jax.tree.map(spec, eng._idle_chunk_args(wb))
+    text = eng._get_chunk(wb).lower(*args).compile().as_text()
+    got = scopes.op_scopes(text)
+    assert set(scopes.SCOPES) <= set(got.values())
+    idle = ("parameter(", "get-tuple-element(", "bitcast(", "constant(",
+            "tuple(", "copy(", "copy-start(", "copy-done(", "custom-call()")
+    for line in text.splitlines():
+        key = scopes.op_key(line)
+        if key in got and got[key] == scopes.UNSCOPED:
+            assert any(op in line for op in idle), line[:200]

@@ -152,7 +152,7 @@ def test_span_without_recorder_is_noop():
     assert obs_trace.current() is None
     with obs_trace.span("x", cat="y", a=1) as h:
         h.set(b=2)  # must not raise
-    obs_trace.instant("z")  # must not raise
+    assert (h.t0, h.t1) == (0.0, 0.0)  # the shared no-op handle
     assert obs_trace.current() is None
 
 
@@ -174,11 +174,12 @@ def test_chrome_export_schema_valid(tmp_path):
         with obs_trace.span("a", cat="x", n=1):
             rec.counter_rows("c", [{"v": 1, "skip": "str"},
                                    {"v": 2}], 0.0, 1.0)
-        rec.instant("mark", note="hi")
+        with obs_trace.span("mark", cat="x", note="hi"):
+            pass
     payload = obs_export.to_chrome(rec, meta={"suite": "unit"})
     assert obs_export.validate(payload) == []
     phs = [e["ph"] for e in payload["traceEvents"]]
-    assert phs.count("C") == 2 and "X" in phs and "i" in phs
+    assert phs.count("C") == 2 and phs.count("X") == 2 and "i" not in phs
     cs = [e for e in payload["traceEvents"] if e["ph"] == "C"]
     assert all("skip" not in e["args"] for e in cs)  # non-numeric filtered
     assert cs[0]["ts"] < cs[1]["ts"]  # interpolated placement
@@ -221,3 +222,179 @@ def test_hist_cap_pow2_buckets():
     assert _hist_cap(1000) == 1024  # no upper clamp
     for s in range(1, 200):
         assert _hist_cap(s) >= s  # a chunk always fits its buffer
+
+
+# -- spans on the profiler's clock -------------------------------------------
+def _host_spans(trace_dir) -> list:
+    """(name, start, end) of every ``<cat>.<name>`` program span on the
+    host plane of the one trace under ``trace_dir``."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    (path,) = Path(trace_dir).rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(ev.name, ev.start_ns, ev.end_ns)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.split(".")[0] in ("engine", "stream")]
+
+
+def _inside(spans, inner: str, outer: str) -> bool:
+    """Every ``inner`` span lies inside some ``outer`` span (and there is
+    at least one)."""
+    outs = [(s, e) for n, s, e in spans if n == outer]
+    ins = [(s, e) for n, s, e in spans if n == inner]
+    return bool(ins) and all(any(os <= s and e <= oe for os, oe in outs)
+                             for s, e in ins)
+
+
+def test_spans_nest_on_the_profiler_clock(tmp_path):
+    """With no recorder installed, a profiler session still sees every
+    program span as ``<cat>.<name>``, nested as the code nests them; the
+    recorder stays empty and the values are those of an unprofiled run."""
+    import jax
+    g = G.powerlaw_graph(400, avg_deg=4, seed=2, weighted=True)
+    cfg = EngineConfig(t2=1e-9, width=4, block_size=128,
+                       repartition_interval=1)
+    batch = synthetic_stream(g, 1, 30, seed=5, delete_frac=0.25,
+                             weighted=True)[0]
+    eng = StructureAwareEngine(g, A.pagerank(), cfg)
+    plain = eng.run()
+    twin = StreamingEngine(g, A.sssp(0), cfg)
+    se = StreamingEngine(g, A.sssp(0), cfg)
+    twin.ingest(batch)
+    assert obs_trace.current() is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = eng.run()
+        se.ingest(batch)
+    finally:
+        jax.profiler.stop_trace()
+    assert obs_trace.current() is None
+    assert np.array_equal(plain.values, res.values)
+    assert _counters(plain.metrics) == _counters(res.metrics)
+    assert np.array_equal(twin.values, se.values)
+    spans = _host_spans(tmp_path)
+    assert _inside(spans, "engine.chunk", "engine.run")
+    assert _inside(spans, "engine.sync", "engine.chunk")
+    assert _inside(spans, "engine.repartition", "engine.boundary")
+    assert _inside(spans, "stream.apply", "stream.ingest")
+    assert _inside(spans, "stream.commit", "stream.apply")
+    assert _inside(spans, "stream.reconverge", "stream.ingest")
+    # one sync and one boundary per chunk
+    count = {n: sum(1 for m, _, _ in spans if m == n)
+             for n in ("engine.chunk", "engine.sync", "engine.boundary")}
+    assert len(set(count.values())) == 1
+
+
+# -- the host-sync counter ----------------------------------------------------
+@pytest.fixture
+def fetches(monkeypatch):
+    """Count the device reads below ``HostSyncs`` independently."""
+    from repro.core import metrics as M
+    seen = []
+
+    def fetch(x):
+        seen.append(1)
+        return np.asarray(x)
+
+    monkeypatch.setattr(M, "_fetch", fetch)
+    return seen
+
+
+@pytest.mark.parametrize("fused,trace", [(True, False), (True, True),
+                                         (False, False)])
+def test_host_syncs_count_every_read(fetches, fused, trace):
+    g = G.powerlaw_graph(400, avg_deg=4, seed=4, weighted=True)
+    eng = StructureAwareEngine(g, A.sssp(0), CFG)
+    res = eng.run(fused=fused, trace=trace)
+    assert res.metrics.host_syncs == len(fetches) > 0
+    if fused:
+        # a read at the start and one at the end; per chunk the seven
+        # chunk results, and the two history buffers when traced
+        per_chunk = 9 if trace else 7
+        assert res.metrics.host_syncs == 2 + per_chunk * len(res.history)
+
+
+def test_stream_batch_host_syncs(fetches):
+    g = G.powerlaw_graph(300, avg_deg=4, seed=3, weighted=True)
+    se = StreamingEngine(g, A.sssp(0), CFG)
+    total = 0
+    for b in synthetic_stream(g, 2, 30, seed=4, delete_frac=0.25,
+                              weighted=True):
+        before = len(fetches)
+        rep = se.ingest(b)
+        assert rep.host_syncs == len(fetches) - before
+        total += rep.host_syncs
+    assert se.metrics.host_syncs == total > 0
+
+
+# -- device operations named by scope ----------------------------------------
+def test_op_scopes_name_the_superstep():
+    from repro.obs import scopes
+    g = G.powerlaw_graph(400, avg_deg=4, seed=1, weighted=True)
+    eng = StructureAwareEngine(g, A.pagerank(), EngineConfig(
+        t2=1e-9, width=4, block_size=128, use_pallas=True))
+    got = eng.op_scopes()
+    assert got and all(scopes.op_key(k) == k for k in got)
+    assert set(got.values()) <= set(scopes.SCOPES) | {scopes.UNSCOPED}
+    assert {"select", "post", "converge", "sweep_fold", "sweep_hot",
+            "sweep_cold", "account", "chunk_loop"} <= set(got.values())
+
+
+HLO = """\
+HloModule jit_chunk
+
+%fused_computation.1 (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %gather.3 = f32[8]{0} gather(f32[8]{0} %param_0), metadata={op_name="jit(chunk)/while/body/vmap(sweep_gather_values)/gather"}
+}
+
+%fused_computation.2 (param_0.1: f32[8]) -> (f32[8], f32[8]) {
+  %param_0.1 = f32[8]{0} parameter(0)
+  %max.1 = f32[8]{0} maximum(%param_0.1, %param_0.1), metadata={op_name="jit(chunk)/while/body/post/max"}
+  ROOT %tuple.9 = (f32[8]{0}, f32[8]{0}) tuple(%max.1, %max.1)
+}
+
+%body.5 (p: (f32[8])) -> (f32[8]) {
+  %p = (f32[8]{0}) parameter(0)
+  %get-tuple-element.1 = f32[8]{0} get-tuple-element(%p), index=0
+  %fusion.7 = f32[8]{0:T(1024)} fusion(%get-tuple-element.1), kind=kCustom, calls=%fused_computation.1
+  %fusion.8 = (f32[8]{0}, f32[8]{0}) fusion(%fusion.7), kind=kLoop, calls=%fused_computation.2
+  ROOT %tuple.2 = (f32[8]{0}) tuple(%fusion.7)
+}
+
+%cond.6 (q: (f32[8])) -> pred[] {
+  %q = (f32[8]{0}) parameter(0)
+  ROOT %constant.4 = pred[] constant(true)
+}
+
+ENTRY %main.9 (values: f32[8]) -> (f32[8]) {
+  %values = f32[8]{0} parameter(0), metadata={op_name="values"}
+  %tuple.1 = (f32[8]{0}) tuple(%values)
+  ROOT %while.3 = (f32[8]{0}) while(%tuple.1), condition=%cond.6, body=%body.5, metadata={op_name="jit(chunk)/chunk_loop/while"}
+}
+"""
+
+
+def test_op_scopes_reads_compiled_text():
+    """Fusions resolve through their root (a tuple root through its
+    operands), loop bodies inherit the loop's scope, fused insides are
+    not operations of their own, and a trace event's name gives the same
+    key as the compiled line."""
+    from repro.obs import scopes
+    got = scopes.op_scopes(HLO)
+    assert got["%fusion.7 = f32[8]{0:T(1024)}"] == "sweep_gather_values"
+    assert got["%fusion.8 = (f32[8]{0}, f32[8]{0})"] == "post"
+    assert got["%while.3 = (f32[8]{0})"] == "chunk_loop"
+    assert got["%get-tuple-element.1 = f32[8]{0}"] == "chunk_loop"
+    assert got["%values = f32[8]{0}"] == scopes.UNSCOPED
+    assert not any(k.startswith(("%gather.3", "%max.1")) for k in got)
+    event = ("%fusion.7 = f32[8]{0:T(1024)} fusion(f32[8]{0} "
+             "%get-tuple-element.1), kind=kCustom, calls=%fused_computation.1")
+    assert scopes.op_key(event) in got
+    assert scopes.scope_of("a/sweep_fold/b/jit(_where)/select_n") == \
+        "sweep_fold"
+    assert scopes.merge([{"k": "post", "j": "select"},
+                         {"k": "select", "j": "select"}]) == \
+        {"k": scopes.UNSCOPED, "j": "select"}
