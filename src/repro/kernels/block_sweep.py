@@ -18,10 +18,10 @@ segmented combine into one ``pallas_call`` per chunk of
 
 Per chunk the kernel reads ``K * L * TILE`` messages, ``K * TILE`` slot
 offsets and the ``(L, C)`` aggregate, and writes the aggregate back; the
-XLA side reads the chunk's four tile rows (src, dst, w, valid) and
-gathers the source values. The chunk loop runs the block's own
-``ceil(tile_cnt / K)`` steps, so a block costs its true edge count, not
-the largest block's.
+XLA side reads each row array (src, dst, w, valid, and cov when masked)
+as one contiguous ``(K, TILE)`` slice and gathers the source values. The
+chunk loop runs the block's own ``ceil(tile_cnt / K)`` steps, so a block
+costs its true edge count, not the largest block's.
 
 Sub-block activity (``subblocks = S > 1``) masks whole tile rows whose
 ``cov`` misses every live sub-range, and a chunk with no live row skips
@@ -225,14 +225,20 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
     repeated processor builds reuse one closure and the downstream jit
     caches stay warm.
 
-    The block's tile run is walked in chunks of :data:`CHUNK_TILES` rows
+    The block's tile run is walked in chunks of ``K`` rows
+    (:data:`CHUNK_TILES`, or the storage's row count if that is smaller)
     by an XLA loop whose trip count is the block's own
-    ``ceil(tile_cnt / K)``. Each step gathers the chunk's source values
-    and applies ``edge_map`` in XLA (a vertex-array gather has no Mosaic
-    lowering), masks invalid slots, rows past the block's run and — with
-    ``subblocks > 1`` — rows whose covered sub-ranges are all inactive
-    to the identity, and hands the chunk to :func:`_combine_kernel`.
-    ``apply`` runs in XLA on the block's aggregate.
+    ``ceil(tile_cnt / K)``. Each step reads every row array as one
+    contiguous slice of ``K`` rows starting at the chunk's first row; a
+    window that would run past the storage's end is shifted back to end
+    at its last row, and the rows it shifts over (the chunk before, or
+    an earlier block's) are masked. It then gathers the chunk's source
+    values and applies ``edge_map`` in XLA (a vertex-array gather has no
+    Mosaic lowering), masks invalid slots, rows outside the chunk's part
+    of the block's run and — with ``subblocks > 1`` — rows whose covered
+    sub-ranges are all inactive to the identity, and hands the chunk to
+    :func:`_combine_kernel`. ``apply`` runs in XLA on the block's
+    aggregate.
 
     Parity with the dense scatter path: ``min``/``max`` are bitwise equal;
     each ``sum`` slot is within :func:`sum_tolerance` of the scatter's
@@ -249,7 +255,8 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
 
     c = block_size
     tile = tile_w
-    k = CHUNK_TILES
+    # a storage of fewer rows than a chunk is read in one window of them all
+    k = min(CHUNK_TILES, int(n_tiles))
     masked = subblocks > 1
     is_sum = program.combine == "sum"
     ident = float(program.identity)
@@ -266,21 +273,32 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
             return agg0
 
         def chunk(i, agg):
-            j = i * k + jnp.arange(k, dtype=jnp.int32)
-            r = jnp.minimum(t0 + j, n_tiles - 1)
-            live = j < cnt
+            # rows [s0, s0 + k): a window past the storage's end is
+            # shifted back to end at its last row (computed here, as
+            # dynamic_slice would clamp it silently), and the rows it
+            # shifts over are masked like rows past the block's run
+            s = t0 + i * k
+            s0 = jnp.minimum(s, n_tiles - k)
+            rid = s0 + jnp.arange(k, dtype=jnp.int32)
+            live = (rid >= s) & (rid < t0 + cnt)
+
+            def rows(a):
+                with jax.named_scope("sweep_gather_rows"):
+                    return lax.dynamic_slice_in_dim(a, s0, k)
+
+            # read before the cond below: vmapped (the cold sweep) it runs
+            # both branches on operands broadcast to the slate, and a
+            # slice taken inside would broadcast the whole arrays first
+            valid, src, w, dstl = map(rows,
+                                      (ed.valid, ed.src, ed.w, ed.dstl))
             if masked:
-                live = live & (gather("sweep_gather_rows", ed.cov, r)
-                               & sub_act).any(axis=1)
+                live = live & (rows(ed.cov) & sub_act).any(axis=1)
 
             def accumulate(agg):
-                ok = gather("sweep_gather_rows", ed.valid, r) & live[:, None]
-                src = gather("sweep_gather_rows", ed.src, r)
-                if lanes:
-                    src = src.reshape(-1)
-                vals = gather("sweep_gather_values", values, src)
-                aux = gather("sweep_gather_aux", ed.aux, src)
-                w = gather("sweep_gather_rows", ed.w, r)
+                ok = valid & live[:, None]
+                idx = src.reshape(-1) if lanes else src
+                vals = gather("sweep_gather_values", values, idx)
+                aux = gather("sweep_gather_aux", ed.aux, idx)
                 if lanes:
                     msg = program.edge_map(vals, aux, w.reshape(-1))
                     msg = jnp.where(ok.reshape(-1, 1), msg, ident)
@@ -289,9 +307,8 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
                     msg = program.edge_map(vals, aux, w)
                     msg = jnp.where(ok, msg, ident)[:, None, :]
                 msg = msg.astype(jnp.float32)
-                dstl = gather("sweep_gather_rows", ed.dstl, r)[:, None, :]
                 with jax.named_scope("sweep_fold"):
-                    return combine(agg, msg, dstl)
+                    return combine(agg, msg, dstl[:, None, :])
 
             if not masked:
                 return accumulate(agg)

@@ -30,7 +30,7 @@ from repro.core import graph as G
 from repro.core.engine import (EngineConfig, StructureAwareEngine,
                                coupling_from_counts)
 from repro.kernels import ops, ref
-from repro.kernels.block_sweep import sum_tolerance
+from repro.kernels.block_sweep import CHUNK_TILES, sum_tolerance
 from repro.serve.lanes import LaneEngine
 from repro.stream import StreamingEngine
 
@@ -142,18 +142,51 @@ def test_fused_sweep_bitwise_property(n, avg, seed, prog, subblocks):
     _assert_counters(rp.metrics, rd.metrics, f"{prog} sb={subblocks}")
 
 
-# -- single-lane engine parity: host-driven reference loop -------------------
-@pytest.mark.parametrize("prog", ["pagerank", "sssp"])
-def test_host_path_bitwise(prog):
-    g = G.powerlaw_graph(300, 4, seed=5, weighted=(prog == "sssp"))
+# -- single-lane engine parity at the edges of the chunk's slice window ------
+# tile geometries (graph n, avg_deg, seed; sub-blocks per block) where a
+# chunk's window of CHUNK_TILES rows meets the storage's ends
+_GEOMETRIES = {
+    # 6 tile rows, fewer than a chunk: one window of them all, with cov
+    "short_s8": ((300, 4, 5), 8),
+    # tile_cnt [11, 2] over 13 rows: the hub's second chunk and the last
+    # block's chunk are shifted back over the hub's live rows
+    "shifted": ((128, 48, 2), 1),
+    "shifted_s8": ((128, 48, 2), 8),
+}
+
+
+def _geometry_pair(prog, geometry, fused):
+    (n, avg, seed), subblocks = _GEOMETRIES[geometry]
+    g = G.powerlaw_graph(n, avg, seed=seed, weighted=(prog == "sssp"))
     program = _PROGRAMS[prog]()
-    kw = dict(t2=1e-9, width=4, block_size=64, subblocks=8)
-    rd = StructureAwareEngine(g, program,
-                              EngineConfig(**kw)).run(fused=False)
-    rp = StructureAwareEngine(
-        g, program, EngineConfig(use_pallas=True, **kw)).run(fused=False)
-    _assert_values(program.combine, rp.values, rd.values, g, f"host {prog}")
-    _assert_counters(rp.metrics, rd.metrics, f"host {prog}")
+    kw = dict(t2=1e-9, width=4, block_size=64, subblocks=subblocks)
+    ed = StructureAwareEngine(g, program, EngineConfig(**kw))
+    ep = StructureAwareEngine(g, program, EngineConfig(use_pallas=True, **kw))
+    store = ep.plan.unified
+    n_tiles = store.src.shape[0]
+    k = min(CHUNK_TILES, n_tiles)
+    starts = [t0 + i for t0, cnt in zip(store.tile_start, store.tile_cnt)
+              for i in range(0, cnt, k)]
+    if geometry.startswith("short"):
+        assert n_tiles < CHUNK_TILES, n_tiles
+    else:
+        assert max(starts) > n_tiles - k, (starts, n_tiles)
+    rd, rp = ed.run(fused=fused), ep.run(fused=fused)
+    label = f"{'fused' if fused else 'host'} {prog} {geometry}"
+    _assert_values(program.combine, rp.values, rd.values, g, label)
+    _assert_counters(rp.metrics, rd.metrics, label)
+
+
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+@pytest.mark.parametrize("prog", ["pagerank", "sssp"])
+def test_host_path_bitwise(prog, geometry):
+    _geometry_pair(prog, geometry, fused=False)
+
+
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+@pytest.mark.parametrize("prog", ["pagerank", "sssp"])
+def test_fused_sweep_window_bitwise(prog, geometry):
+    _geometry_pair(prog, geometry, fused=True)
 
 
 # -- lane-batched parity (the PPR scatter fix) -------------------------------

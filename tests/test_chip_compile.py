@@ -12,6 +12,8 @@ The topology is described inside a module fixture, never while a module
 is imported: the description loads the TPU library, which only one
 process may hold.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -75,9 +77,19 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("form", ["row", "vmap"])
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
-def test_block_sweep_compiles_for_v5e(variant, form, one_chip, no_cache):
+@pytest.fixture(scope="module")
+def compiled_sweep(one_chip, no_cache):
+    """``(variant, form) -> compiled text``, each compiled once a module."""
+    memo = {}
+
+    def build(variant, form):
+        if (variant, form) not in memo:
+            memo[variant, form] = _compile_sweep(variant, form, one_chip)
+        return memo[variant, form]
+    return build
+
+
+def _compile_sweep(variant, form, one_chip):
     factory, lanes, subblocks = VARIANTS[variant]
     sweep = make_block_sweep(
         factory(), TILE_START, TILE_CNT, n_tiles=N_TILES, tile_w=TILE,
@@ -103,10 +115,38 @@ def test_block_sweep_compiles_for_v5e(variant, form, one_chip, no_cache):
     fn = (jax.vmap(one, in_axes=(None, None, None, 0, 0)) if form == "vmap"
           else one)
     row_shape = (2,) if form == "vmap" else ()
-    compiled = jax.jit(fn).lower(
+    return jax.jit(fn).lower(
         ed, vals, vals, _spec(row_shape, jnp.int32, one_chip),
-        _spec(row_shape + (subblocks,), jnp.bool_, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        _spec(row_shape + (subblocks,), jnp.bool_, one_chip)).compile(
+        ).as_text()
+
+
+@pytest.mark.parametrize("form", ["row", "vmap"])
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_block_sweep_compiles_for_v5e(variant, form, compiled_sweep):
+    assert "tpu_custom_call" in compiled_sweep(variant, form)
+
+
+@pytest.mark.parametrize("form", ["row", "vmap"])
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_tile_rows_are_sliced_for_v5e(variant, form, compiled_sweep):
+    """Each chunk reads its tile rows as contiguous slices: no ``gather``
+    of the compiled sweep is under ``sweep_gather_rows``, which still
+    names the ops that load the rows (in the engine's fused chunk too,
+    ``test_chunk_scopes_for_v5e``)."""
+    from repro.obs import scopes
+    text = compiled_sweep(variant, form)
+    row_gathers = [
+        line[:200] for line in text.splitlines()
+        if re.search(r"= \S+ gather\(", line)
+        and scopes.scope_of(_op_name(line)) == "sweep_gather_rows"]
+    assert not row_gathers, row_gathers
+    assert "sweep_gather_rows" in scopes.op_scopes(text).values()
+
+
+def _op_name(line):
+    m = re.search(r'op_name="([^"]*)"', line)
+    return m.group(1) if m else ""
 
 
 def test_chunk_scopes_for_v5e(one_chip, no_cache, monkeypatch):
