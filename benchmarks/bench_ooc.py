@@ -6,9 +6,9 @@ graph and config so the rows isolate the spill tier's contribution:
   * ``ooc_budget`` — the engine converges BITWISE-identically (values and
     algorithmic counters) under shrinking device budgets; the rows track
     the paging overhead (spill traffic, prefetch hit rate, slowdown vs
-    fully resident) as the budget tightens. The floor row runs at
-    ``width + 2`` resident blocks — the minimum the admission guarantee
-    allows.
+    fully resident) as the device pool of tile rows shrinks. The floor
+    row runs at the ``width + 2`` largest block runs — the minimum the
+    admission guarantee allows.
   * ``ooc_restart`` — save_epoch -> restore(verify=True) reconverges from
     the checkpointed fixpoint in a fraction of the cold-start supersteps;
     the derived field carries the warm/cold TTC and iteration ratios that
@@ -37,6 +37,8 @@ def run(n: int = 20000):
     # -- budget sweep: fully resident baseline, then tightening budgets ----
     full_eng = StructureAwareEngine(g, A.pagerank(), cfg)
     P = full_eng.plan.num_blocks
+    cnt = full_eng.plan.unified.tile_cnt
+    total = int(cnt.sum())
     t0 = time.perf_counter()
     full = full_eng.run()
     us_full = (time.perf_counter() - t0) * 1e6
@@ -44,15 +46,15 @@ def run(n: int = 20000):
         "ooc/powerlaw/pagerank/resident_all", us_full,
         f"P={P};iters={full.metrics.iterations};"
         f"bytes_loaded={full.metrics.bytes_loaded}"))
-    floor = cfg.width + 2
-    budgets = sorted({max(3 * P // 4, floor), max(P // 2, floor), floor},
-                     reverse=True)
+    floor = int(np.sort(cnt)[::-1][:cfg.width + 2].sum())
+    budgets = sorted({max(3 * total // 4, floor), max(total // 2, floor),
+                      floor}, reverse=True)
     for budget in budgets:
-        if budget >= P:
+        if budget >= total:
             continue
         eng = StructureAwareEngine(
             g, A.pagerank(),
-            dataclasses.replace(cfg, resident_blocks=budget))
+            dataclasses.replace(cfg, resident_rows=budget))
         t0 = time.perf_counter()
         res = eng.run()
         us = (time.perf_counter() - t0) * 1e6
@@ -60,7 +62,7 @@ def run(n: int = 20000):
         bitwise = np.array_equal(full.values, res.values)
         rows.append((
             f"ooc/powerlaw/pagerank/resident{budget}", us,
-            f"P={P};budget={budget};iters={m.iterations};"
+            f"P={P};rows={total};budget={budget};iters={m.iterations};"
             f"bitwise={bitwise};evictions={m.spill_evictions};"
             f"spilled_mb={m.bytes_spilled / 1e6:.1f};"
             f"fetched_mb={m.bytes_fetched / 1e6:.1f};"

@@ -8,16 +8,17 @@ batch. Each batch re-heats only the dirty blocks and reconverges from the
 previous fixpoint inside the already-compiled fused superstep; the cold
 column reruns the full convergence from scratch on the same mutated graph.
 
-With ``--resident-blocks`` the warm engine additionally runs OUT OF CORE:
-only that many partition blocks keep their edge tiles on device, the rest
-spill to a host/disk tier and page back in ahead of the schedule — the
+With ``--resident-rows`` the warm engine additionally runs OUT OF CORE:
+the device holds a pool of that many edge tile rows, the rest of the
+graph's rows spill to a host/disk tier and page back in ahead of the
+schedule — the
 values stay bitwise-identical to the fully resident run. ``--snapshot-dir``
 then demos epoch persistence: save the live epoch, restore it in a fresh
 engine, and warm-reconverge in a handful of supersteps instead of a cold
 start.
 
     PYTHONPATH=src python examples/streaming_graph.py [--n 10000] \
-        [--resident-blocks 8] [--snapshot-dir /tmp/epoch]
+        [--resident-rows 60] [--snapshot-dir /tmp/epoch]
 """
 import argparse
 import dataclasses
@@ -38,12 +39,12 @@ def main():
     ap.add_argument("--subblocks", type=int, default=1,
                     help="sub-blocks per partition block (hierarchical "
                          "activity tracking; 1 = flat blocks)")
-    ap.add_argument("--resident-blocks", type=int, default=None,
-                    help="device budget for the warm engine's edge tiles "
+    ap.add_argument("--resident-rows", type=int, default=None,
+                    help="device pool for the warm engine's edge tile rows "
                          "(out-of-core; default: fully resident)")
     ap.add_argument("--spill-dir", default=None,
                     help="spill evicted tiles to npz segments here instead "
-                         "of the host cache (needs --resident-blocks)")
+                         "of the host cache (needs --resident-rows)")
     ap.add_argument("--snapshot-dir", default=None,
                     help="save the final epoch here, then restore + "
                          "warm-reconverge a fresh engine from it")
@@ -55,7 +56,7 @@ def main():
                        subblocks=args.subblocks)
     prog = A.pagerank()
 
-    warm_cfg = dataclasses.replace(cfg, resident_blocks=args.resident_blocks,
+    warm_cfg = dataclasses.replace(cfg, resident_rows=args.resident_rows,
                                    spill_dir=args.spill_dir)
     warm = StreamingEngine(g, prog, warm_cfg)
     cold = StreamingEngine(g, prog, cfg, StreamConfig(warm=False))
@@ -99,14 +100,15 @@ def main():
               f"sub-block fraction {mw.subblock_dirty_frac:.2f} vs block "
               f"fraction {mw.dirty_frac:.2f}, mean sub-blocks swept per "
               f"block load {mw.mean_subblock_dispatch:.2f}")
-    if args.resident_blocks is not None:
-        P = warm.engine.plan.num_blocks
+    if args.resident_rows is not None:
+        rows = warm.engine.plan.unified.src.shape[0]
         init = warm.initial_result.metrics
         # paging never changes the schedule, so the budget run is
         # bitwise-equal to a fully resident warm engine (the property
         # tests in tests/test_ooc.py pin this); here the cold column
         # already cross-checks the converged values above
-        print(f"out-of-core: {args.resident_blocks}/{P} blocks resident; "
+        print(f"out-of-core: {args.resident_rows}/{rows} tile rows "
+              f"resident; "
               f"spill traffic incl. initial run: "
               f"{mw.spill_evictions + init.spill_evictions} evictions, "
               f"{(mw.bytes_spilled + init.bytes_spilled) / 1e6:.1f} MB out, "

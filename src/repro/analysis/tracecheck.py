@@ -267,9 +267,8 @@ def check_one_executable_per(contracts: list[Contract]) -> list[Finding]:
             # mode) must return the identical sweep closure
             from repro.kernels import block_sweep as bs
             store = eng.plan.unified
-            args = (eng.program, store.tile_start, store.tile_cnt)
-            kwargs = dict(n_tiles=int(store.src.shape[0]),
-                          tile_w=int(store.src.shape[1]),
+            args = (eng.program, store.tile_cnt)
+            kwargs = dict(tile_w=int(store.src.shape[1]),
                           block_size=eng.plan.block_size,
                           n_total=eng.plan.graph.n)
             first = fn(*args, **kwargs)

@@ -33,8 +33,9 @@ shard_map distributed engine).
 Dynamic edge state (streaming support). The tiled edge arrays, the
 per-vertex aux, and the staleness-coupling matrix are **traced arguments**
 of every jitted function (:class:`EdgeData`), not closure constants: the
-compiled superstep is keyed only on the tile GEOMETRY (tile_start /
-tile_cnt / shapes), so the streaming subsystem can mutate edges in place
+compiled superstep is keyed only on the tile GEOMETRY (tile_cnt /
+shapes; each run's first row is the traced table ``EdgeData.start``), so
+the streaming subsystem can mutate edges in place
 and re-enter the same executable — a closure-captured array would bake the
 edge list into the XLA program and force a recompile per delta batch.
 ``run(warm=WarmStart(...))`` re-enters convergence from an
@@ -149,14 +150,15 @@ class EngineConfig:
     subblocks: int = 1  # sub-blocks per block (hierarchical activity tracking)
     retire_after: int = 3  # consecutive sub-floor supersteps before retire
     min_width: int = 2  # narrowest dispatch-width bucket
-    # out-of-core block tier: device memory modeled as a fixed budget of
-    # resident block slots. None (default) = fully resident — no spill
-    # tier is built and the trajectory is bitwise-identical to before the
-    # tier existed. With resident_blocks < P the engine evicts cold
-    # blocks' edge tile rows to host/disk (repro.ooc.store) and pages the
-    # predicted schedule back in before each superstep; budget must be
-    # >= width + 2 (the scheduled slate plus the pinned pad blocks).
-    resident_blocks: int | None = None
+    # out-of-core block tier: the device holds a pool of this many edge
+    # tile rows, and EdgeData's row arrays are sized to it. None (default)
+    # or a budget of at least the plan's rows = fully resident: no spill
+    # tier, and the row arrays are the plan's. Below that the engine keeps
+    # each resident block's run contiguous in the pool, spills cold
+    # blocks' rows to host/disk (repro.ooc.store) and pages the predicted
+    # schedule in before each superstep; the pool must hold one
+    # superstep's demand, the width + 2 largest runs (pins included).
+    resident_rows: int | None = None
     spill_dir: str | None = None  # npz segment dir; None = host cache only
     tile_slack: float = 0.0  # spare tile capacity per block (streaming)
     spare_tiles: int = 0  # flat extra tiles per block (streaming)
@@ -206,14 +208,24 @@ class EdgeData(NamedTuple):
     """Device-resident dynamic state of the tiled layout — everything a
     delta batch can change without changing tile geometry. Passed as a
     traced argument to every jitted engine function (NOT closed over), so
-    in-place streaming mutation re-uses the compiled executable."""
+    in-place streaming mutation re-uses the compiled executable.
 
-    src: jax.Array  # (n_tiles, TILE) int32
-    dstl: jax.Array  # (n_tiles, TILE) int32
-    w: jax.Array  # (n_tiles, TILE) float32
-    valid: jax.Array  # (n_tiles, TILE) bool
-    cov: jax.Array  # (n_tiles, S) bool — sub-block dst coverage per tile
+    Block b's tile rows are ``[start[b], start[b] + tile_cnt[b])`` of the
+    row arrays. Fully resident, the row arrays are the plan's and
+    ``start`` its ``tile_start``; under an out-of-core budget they are a
+    pool of ``resident_rows`` rows, ``start`` is where each resident
+    block's run sits in it, and ``resident`` marks the blocks whose run
+    is there (the sweeps of any other block are counted, and a run with
+    one raises)."""
+
+    src: jax.Array  # (n_rows, TILE) int32
+    dstl: jax.Array  # (n_rows, TILE) int32
+    w: jax.Array  # (n_rows, TILE) float32
+    valid: jax.Array  # (n_rows, TILE) bool
+    cov: jax.Array  # (n_rows, S) bool — sub-block dst coverage per tile
     aux: jax.Array  # (n,) float32 per-vertex constant (e.g. out-degree)
+    start: jax.Array  # (P,) int32 first row of each block's run
+    resident: jax.Array  # (P,) bool: the block's run is in the row arrays
 
 
 def tile_coverage(dst_local, valid, subblocks: int,
@@ -237,13 +249,17 @@ def tile_coverage(dst_local, valid, subblocks: int,
 
 def edge_data(store: TiledStorage, aux, subblocks: int = 1,
               block_size: int | None = None) -> EdgeData:
+    """The whole storage on the device, each block's run at its
+    ``tile_start``."""
     return EdgeData(src=jnp.asarray(store.src),
                     dstl=jnp.asarray(store.dst_local),
                     w=jnp.asarray(store.w), valid=jnp.asarray(store.valid),
                     cov=jnp.asarray(tile_coverage(
                         store.dst_local, store.valid, subblocks,
                         block_size)),
-                    aux=jnp.asarray(aux))
+                    aux=jnp.asarray(aux),
+                    start=jnp.asarray(store.tile_start, dtype=jnp.int32),
+                    resident=jnp.ones(store.num_blocks, dtype=bool))
 
 
 # -- adaptive-schedule decision helpers --------------------------------------
@@ -397,9 +413,10 @@ def make_tiled_processor(program: VertexProgram, store: TiledStorage,
     """Block processor over the unified tiled layout: ``row`` is the GLOBAL
     block id and the per-block work is a fori over that block's tile rows,
     so compute scales with the block's true edge count rather than a shared
-    padded capacity. Only the tile GEOMETRY (tile_start/tile_cnt) is closed
-    over; the edge arrays and aux arrive per call as an :class:`EdgeData`,
-    so streaming mutations never invalidate the trace.
+    padded capacity. Only the tile GEOMETRY (tile_cnt) is closed over; the
+    edge arrays, aux and each run's first row (``ed.start``) arrive per
+    call as an :class:`EdgeData`, so streaming mutations and out-of-core
+    placement never invalidate the trace.
 
     With ``subblocks = S > 1`` the processors take a ``sub_act`` (S,) bool
     mask (which of the block's S equal sub-ranges are live) and return
@@ -410,7 +427,6 @@ def make_tiled_processor(program: VertexProgram, store: TiledStorage,
     stops paying its whole edge slice). ``sub_act=None`` (the S = 1 path)
     traces to EXACTLY the flat per-block code — bitwise parity with the
     non-hierarchical engine is by construction, not by rounding luck."""
-    tile_start = jnp.asarray(store.tile_start, dtype=jnp.int32)
     tile_cnt = jnp.asarray(store.tile_cnt, dtype=jnp.int32)
     gids = jnp.arange(store.num_blocks, dtype=jnp.int32)
     c = block_size
@@ -442,7 +458,7 @@ def make_tiled_processor(program: VertexProgram, store: TiledStorage,
             base = row * c
             old = lax.dynamic_slice(values, (base,), (c,))
         else:
-            t0 = tile_start[row]
+            t0 = ed.start[row]
 
             def tile_compute(t, agg):
                 r = t0 + t
@@ -537,7 +553,6 @@ def make_lane_processor(program: LaneProgram, store: TiledStorage,
     leading sub-block axis — (S, L) — mirroring
     :func:`make_tiled_processor`; ``sub_act=None`` is the exact flat
     path."""
-    tile_start = jnp.asarray(store.tile_start, dtype=jnp.int32)
     tile_cnt = jnp.asarray(store.tile_cnt, dtype=jnp.int32)
     gids = jnp.arange(store.num_blocks, dtype=jnp.int32)
     c = block_size
@@ -588,7 +603,7 @@ def make_lane_processor(program: LaneProgram, store: TiledStorage,
             cnt = jnp.maximum(vmask.reshape(subblocks, sub).sum(axis=1), 1)
             return (base, new, dsub.sum(axis=1) / cnt[:, None],
                     dsub.max(axis=1))
-        t0 = tile_start[row]
+        t0 = ed.start[row]
         if program.combine == "sum":
             agg0 = jnp.zeros((c, nl), jnp.float32)
         else:
@@ -688,8 +703,6 @@ class StructureAwareEngine:
         # Per-block true edge counts: a MUTABLE copy (streaming updates it);
         # feeds the exact metric accounting and the bytes cost model.
         self.edge_counts = np.array(p.unified.edges, dtype=np.int64)
-        self._ed = edge_data(p.unified, self.aux, self.config.subblocks,
-                             p.block_size)
         self._block_affects = self._build_block_affects()
         self._coupling = self._build_coupling_matrix()
         self._coupling_dev = jnp.asarray(self._coupling)
@@ -708,11 +721,15 @@ class StructureAwareEngine:
         self.last_psd: np.ndarray | None = None
         self.last_calm: np.ndarray | None = None
         self.spill = None
-        if (config.resident_blocks is not None
-                and config.resident_blocks < p.num_blocks):
+        if (config.resident_rows is not None
+                and config.resident_rows < p.unified.src.shape[0]):
             from repro.ooc.store import SpillStore  # avoid import cycle
-            self.spill = SpillStore(self, config.resident_blocks,
+            self.spill = SpillStore(self, config.resident_rows,
                                     directory=config.spill_dir)
+            self._ed = self.spill.initial_edge_data(self.aux)
+        else:
+            self._ed = edge_data(p.unified, self.aux, config.subblocks,
+                                 p.block_size)
 
     # -- one-time host preprocessing ---------------------------------------
     def _init_dead(self):
@@ -856,13 +873,12 @@ class StructureAwareEngine:
         them — a caller that must keep reading this epoch across future
         commits (the query service's snapshot isolation) copies first.
         O(m) device bytes, zero host traffic — except under an
-        out-of-core budget, where the snapshot's spilled holes are
-        materialized from the spill tier's truth (residency unchanged):
-        a pinned epoch must survive the eviction of its blocks."""
-        ed = EdgeData(*(jnp.array(a) for a in self._ed))
+        out-of-core budget, where the snapshot is the whole plan built from
+        the pool and the spill tier's truth (residency unchanged): a
+        pinned epoch must survive the eviction of its blocks."""
         if self.spill is not None:
-            ed = self.spill.materialize(ed)
-        return ed
+            return self.spill.materialize()
+        return EdgeData(*(jnp.array(a) for a in self._ed))
 
     @property
     def edge_state(self) -> EdgeData:
@@ -889,7 +905,7 @@ class StructureAwareEngine:
             cov = jnp.asarray(tile_coverage(
                 np.asarray(new_dstl), np.asarray(new_valid),
                 self.config.subblocks, self.plan.block_size))
-        new = EdgeData(
+        new = ed._replace(
             src=jnp.asarray(src, jnp.int32) if src is not None else ed.src,
             dstl=new_dstl,
             w=jnp.asarray(w, jnp.float32) if w is not None else ed.w,
@@ -926,13 +942,18 @@ class StructureAwareEngine:
     _ROW_CHUNK = 16  # tile rows per scatter call (~100KB payload)
     _AUX_CHUNK = 256  # aux entries per scatter call
     _COUPLING_CHUNK = 16  # coupling rows per scatter call
+    # pool rows per out-of-core placement call: bulk chunks of the first
+    # size, then the rest padded to the second (little padding, few calls)
+    _PLACE_CHUNKS = (128, 16)
+    _MOVE_CHUNK = 64  # pool rows per out-of-core compaction move call
 
     @one_executable_per("scatter-type")
     def _chunked_scatter(self, key: str, arrays: tuple, idx: np.ndarray,
-                         payloads: list, chunk: int) -> tuple[tuple, int]:
+                         payloads: list, chunk: int,
+                         scope: str | None = None) -> tuple[tuple, int]:
         """Scatter ``payloads`` into ``arrays`` at ``idx`` in fixed-size
-        chunks through one cached donated jit. Returns (new arrays, padded
-        entry count)."""
+        chunks through one cached donated jit (its ops under the named
+        ``scope``, if any). Returns (new arrays, padded entry count)."""
         k = int(idx.size)
         pk = -(-k // chunk) * chunk
         if pk != k:
@@ -948,6 +969,8 @@ class StructureAwareEngine:
                 arrs, r, ps = args[:na], args[na], args[na + 1:]
                 return tuple(a.at[r].set(p) for a, p in zip(arrs, ps))
 
+            if scope is not None:
+                scatter = jax.named_scope(scope)(scatter)
             fn = jax.jit(scatter, donate_argnums=tuple(range(na)))
             self._fns[key] = fn
         for at in range(0, pk, chunk):
@@ -955,28 +978,91 @@ class StructureAwareEngine:
                         *(jnp.asarray(p[at:at + chunk]) for p in payloads))
         return arrays, pk
 
-    def update_edge_rows(self, rows: np.ndarray, *, src, dst_local, w,
-                         valid) -> int:
-        """Scatter updated TILE ROWS into the device-resident EdgeData.
-        ``rows`` are unified-tile row indices; the payloads are the matching
-        (len(rows), TILE) slices. Returns the transferred bytes (chunked
-        payload + indices)."""
-        rows = np.asarray(rows, dtype=np.int32)
-        if rows.size == 0:
-            return 0
+    def _write_rows(self, key: str, rows: np.ndarray, payload: dict,
+                    chunk: int, scope: str | None = None) -> int:
+        """Scatter (len(rows), TILE) payloads into the device row arrays
+        at ``rows`` (indices of those arrays). Returns the transferred
+        bytes (chunked payload + indices)."""
         ed = self._ed
-        cov = tile_coverage(dst_local, valid, self.config.subblocks,
-                            self.plan.block_size)
+        cov = tile_coverage(payload["dst_local"], payload["valid"],
+                            self.config.subblocks, self.plan.block_size)
         (ns, nd, nw, nv, nc), pk = self._chunked_scatter(
-            "row_scatter", (ed.src, ed.dstl, ed.w, ed.valid, ed.cov), rows,
-            [np.asarray(src, np.int32), np.asarray(dst_local, np.int32),
-             np.asarray(w, np.float32), np.asarray(valid, bool), cov],
-            self._ROW_CHUNK)
-        self._ed = EdgeData(src=ns, dstl=nd, w=nw, valid=nv, cov=nc,
-                            aux=ed.aux)
+            key, (ed.src, ed.dstl, ed.w, ed.valid, ed.cov),
+            np.asarray(rows, dtype=np.int32),
+            [np.asarray(payload["src"], np.int32),
+             np.asarray(payload["dst_local"], np.int32),
+             np.asarray(payload["w"], np.float32),
+             np.asarray(payload["valid"], bool), cov], chunk, scope)
+        self._ed = ed._replace(src=ns, dstl=nd, w=nw, valid=nv, cov=nc)
         # 4B src + 4B dst offset + 4B w + 1B valid per slot + 1B per
         # sub-block coverage bit + 4B row index
         return pk * (int(ns.shape[1]) * 13 + int(nc.shape[1]) + 4)
+
+    def update_edge_rows(self, rows: np.ndarray, *, src, dst_local, w,
+                         valid) -> int:
+        """Scatter updated TILE ROWS into the device-resident EdgeData.
+        ``rows`` are unified-tile (plan) row indices; the payloads are the
+        matching (len(rows), TILE) slices. Under an out-of-core budget a
+        resident block's rows land where its run sits in the pool, and a
+        spilled block's are dropped (the spill tier's truth holds them).
+        Returns the transferred bytes (chunked payload + indices)."""
+        rows = np.asarray(rows, dtype=np.int64)
+        payload = {"src": src, "dst_local": dst_local, "w": w,
+                   "valid": valid}
+        if self.spill is not None:
+            keep, rows = self.spill.pool_rows(rows)
+            payload = {k: np.asarray(v)[keep] for k, v in payload.items()}
+        if rows.size == 0:
+            return 0
+        return self._write_rows("row_scatter", rows, payload,
+                                self._ROW_CHUNK)
+
+    def write_pool_rows(self, rows: np.ndarray, payload: dict) -> int:
+        """The out-of-core tier's placement: block payloads written at
+        pool rows, under the named scope ``ooc_place``. Returns the
+        transferred bytes."""
+        big, small = self._PLACE_CHUNKS
+        cut = rows.size // big * big
+        sent = 0
+        for lo, hi, k in ((0, cut, big), (cut, rows.size, small)):
+            if hi > lo:
+                sent += self._write_rows(
+                    f"pool_place_{k}", rows[lo:hi],
+                    {f: a[lo:hi] for f, a in payload.items()}, k,
+                    scope="ooc_place")
+        return sent
+
+    def move_pool_rows(self, src_rows: np.ndarray,
+                       dst_rows: np.ndarray) -> None:
+        """Copy pool rows ``src_rows[i]`` to ``dst_rows[i]`` in place
+        (donated, under ``ooc_place``), in order, one fixed-size chunk
+        per call: the out-of-core tier's compaction. Safe for runs moved
+        towards the front in order of their first row: every row is read
+        before a later chunk can write over it."""
+        fn = self._fns.get("pool_move")
+        if fn is None:
+            @jax.named_scope("ooc_place")
+            def move(src, dstl, w, valid, cov, s, d):
+                return tuple(a.at[d].set(a[s])
+                             for a in (src, dstl, w, valid, cov))
+            fn = jax.jit(move, donate_argnums=(0, 1, 2, 3, 4))
+            self._fns["pool_move"] = fn
+        k = self._MOVE_CHUNK
+        src_rows = np.asarray(src_rows, dtype=np.int32)
+        dst_rows = np.asarray(dst_rows, dtype=np.int32)
+        ed = self._ed
+        arrays = (ed.src, ed.dstl, ed.w, ed.valid, ed.cov)
+        for at in range(0, src_rows.size, k):
+            # a short last chunk repeats its own last pair: the same row
+            # read and written again within one call
+            s, d = src_rows[at:at + k], dst_rows[at:at + k]
+            if s.size < k:
+                s = np.concatenate([s, np.full(k - s.size, s[-1], np.int32)])
+                d = np.concatenate([d, np.full(k - d.size, d[-1], np.int32)])
+            arrays = fn(*arrays, jnp.asarray(s), jnp.asarray(d))
+        src, dstl, w, valid, cov = arrays
+        self._ed = ed._replace(src=src, dstl=dstl, w=w, valid=valid,
+                               cov=cov)
 
     def update_aux(self, idx: np.ndarray, vals: np.ndarray) -> int:
         """Scatter changed per-vertex aux entries into the device-resident
@@ -1141,6 +1227,9 @@ class StructureAwareEngine:
             ok = np.zeros(w, dtype=bool)
             rows[:chunk.size] = chunk.astype(np.int32)
             ok[:chunk.size] = True
+            if self.spill is not None:
+                self.spill.check_sweeps(
+                    int((~self.spill.resident[rows]).sum()), None)
             fn = self._get_fn(sequential, w)
             values, psd, dmax = fn(self._ed, values, psd, dmax,
                                    jnp.asarray(rows), jnp.asarray(ok))
@@ -1179,7 +1268,12 @@ class StructureAwareEngine:
         at traced index ``it - it0``. The buffers ride the existing
         boundary sync, so per-superstep resolution costs zero extra host
         round-trips, and the algorithmic carry math is untouched — the
-        traced trajectory is bitwise the untraced one."""
+        traced trajectory is bitwise the untraced one.
+
+        Both count the dispatch slots (padded ones included) that swept a
+        block whose run is not in the row arrays (``ed.resident``): the
+        chunk's ``miss``, 0 whenever the out-of-core tier paged in what
+        the schedule read."""
         width = self.config.width if width is None else width
         key = ("chunk", width, trace_cap)
         if key in self._fns:
@@ -1195,7 +1289,7 @@ class StructureAwareEngine:
         floor = self._psd_floor()
 
         def superstep(it, i2, ed, coupling, values, psd, dmax, calm, counts,
-                      hslots, sbacc, is_hot):
+                      hslots, sbacc, miss, is_hot):
             with jax.named_scope("select"):
                 hot_rows, hot_ok, cold_rows, cold_ok = select(it, i2, psd,
                                                               is_hot)
@@ -1207,6 +1301,9 @@ class StructureAwareEngine:
                 live = (psd >= floor).sum(axis=-1).astype(jnp.int32)
                 sbacc = sbacc + jnp.where(hot_ok, live[hot_rows], 0).sum() \
                     + jnp.where(cold_ok, live[cold_rows], 0).sum()
+                miss = miss + (~ed.resident[hot_rows]).sum(
+                    dtype=jnp.int32) + (~ed.resident[cold_rows]).sum(
+                    dtype=jnp.int32)
             values, psd, dmax = hot_sweep(ed, values, psd, dmax, hot_rows,
                                           hot_ok)
             values, psd, dmax = cold_sweep(ed, values, psd, dmax, cold_rows,
@@ -1218,21 +1315,22 @@ class StructureAwareEngine:
             # staleness propagation + calm/retire counter advance
             psd, dmax, calm = post(coupling, psd, dmax, calm)
             scheduled = hot_ok.any() | cold_ok.any()
-            return values, psd, dmax, calm, counts, hslots, sbacc, scheduled
+            return (values, psd, dmax, calm, counts, hslots, sbacc, miss,
+                    scheduled)
 
         def chunk(ed, coupling, values, psd, dmax, calm, counts, hslots,
                   sbacc, it0, it_end, is_hot, i2):
             def cond(carry):
-                it, _, _, _, _, _, _, _, done = carry
+                it, done = carry[0], carry[-1]
                 return (it < it_end) & jnp.logical_not(done)
 
             def body(carry):
-                it, values, psd, dmax, calm, counts, hslots, sbacc, _ = \
-                    carry
-                (values, psd, dmax, calm, counts, hslots, sbacc,
+                (it, values, psd, dmax, calm, counts, hslots, sbacc, miss,
+                 _) = carry
+                (values, psd, dmax, calm, counts, hslots, sbacc, miss,
                  scheduled) = superstep(it, i2, ed, coupling, values, psd,
                                         dmax, calm, counts, hslots, sbacc,
-                                        is_hot)
+                                        miss, is_hot)
                 with jax.named_scope("converge"):
                     conv = state_lib.converged_device(psd, t2)
                 # empty schedule: no iteration happened (host parity: the
@@ -1240,18 +1338,18 @@ class StructureAwareEngine:
                 it = it + jnp.where(scheduled, 1, 0).astype(it.dtype)
                 done = conv | jnp.logical_not(scheduled)
                 return (it, values, psd, dmax, calm, counts, hslots, sbacc,
-                        done)
+                        miss, done)
 
             with jax.named_scope("chunk_loop"):
-                (it, values, psd, dmax, calm, counts, hslots, sbacc,
+                (it, values, psd, dmax, calm, counts, hslots, sbacc, miss,
                  _) = lax.while_loop(
                     cond, body,
                     (it0, values, psd, dmax, calm, counts, hslots, sbacc,
-                     jnp.bool_(False)))
+                     jnp.int32(0), jnp.bool_(False)))
             with jax.named_scope("converge"):
                 conv = state_lib.converged_device(psd, t2)
             return (it, values, psd, dmax, calm, counts, hslots, sbacc,
-                    conv)
+                    conv, miss)
 
         if trace_cap is None:
             fn = jax.jit(chunk, donate_argnums=(2, 3, 4, 5, 6, 7, 8))
@@ -1264,8 +1362,8 @@ class StructureAwareEngine:
         adaptive = cfg.adaptive
 
         def superstep_traced(it, it0, i2, ed, coupling, values, psd, dmax,
-                             calm, counts, hslots, sbacc, hist_i, hist_f,
-                             is_hot, acct):
+                             calm, counts, hslots, sbacc, miss, hist_i,
+                             hist_f, is_hot, acct):
             # re-derive the slate for the delta accounting: pure repeat of
             # the select inside ``superstep`` (identical inputs), so XLA
             # CSEs it away — and even uncached it could only duplicate
@@ -1273,10 +1371,10 @@ class StructureAwareEngine:
             with jax.named_scope("select"):
                 hot_rows, hot_ok, cold_rows, cold_ok = select(it, i2, psd,
                                                               is_hot)
-            (values, psd, dmax, calm, counts, hslots, sbacc,
+            (values, psd, dmax, calm, counts, hslots, sbacc, miss,
              scheduled) = superstep(it, i2, ed, coupling, values, psd,
                                     dmax, calm, counts, hslots, sbacc,
-                                    is_hot)
+                                    miss, is_hot)
             # per-superstep counter delta through the SAME acct table the
             # host multiplies at the boundary flush: the timeline rows sum
             # exactly to the aggregate Metrics counters by construction
@@ -1302,7 +1400,7 @@ class StructureAwareEngine:
                                               (idx, 0))
             hist_f = lax.dynamic_update_slice(hist_f, row_f[None, :],
                                               (idx, 0))
-            return (values, psd, dmax, calm, counts, hslots, sbacc,
+            return (values, psd, dmax, calm, counts, hslots, sbacc, miss,
                     hist_i, hist_f, scheduled)
 
         def chunk_traced(ed, coupling, values, psd, dmax, calm, counts,
@@ -1312,29 +1410,30 @@ class StructureAwareEngine:
                 return (carry[0] < it_end) & jnp.logical_not(carry[-1])
 
             def body(carry):
-                (it, values, psd, dmax, calm, counts, hslots, sbacc,
+                (it, values, psd, dmax, calm, counts, hslots, sbacc, miss,
                  hist_i, hist_f, _) = carry
-                (values, psd, dmax, calm, counts, hslots, sbacc, hist_i,
-                 hist_f, scheduled) = superstep_traced(
+                (values, psd, dmax, calm, counts, hslots, sbacc, miss,
+                 hist_i, hist_f, scheduled) = superstep_traced(
                     it, it0, i2, ed, coupling, values, psd, dmax, calm,
-                    counts, hslots, sbacc, hist_i, hist_f, is_hot, acct)
+                    counts, hslots, sbacc, miss, hist_i, hist_f, is_hot,
+                    acct)
                 with jax.named_scope("converge"):
                     conv = state_lib.converged_device(psd, t2)
                 it = it + jnp.where(scheduled, 1, 0).astype(it.dtype)
                 done = conv | jnp.logical_not(scheduled)
                 return (it, values, psd, dmax, calm, counts, hslots,
-                        sbacc, hist_i, hist_f, done)
+                        sbacc, miss, hist_i, hist_f, done)
 
             with jax.named_scope("chunk_loop"):
-                (it, values, psd, dmax, calm, counts, hslots, sbacc, hist_i,
-                 hist_f, _) = lax.while_loop(
+                (it, values, psd, dmax, calm, counts, hslots, sbacc, miss,
+                 hist_i, hist_f, _) = lax.while_loop(
                     cond, body,
                     (it0, values, psd, dmax, calm, counts, hslots, sbacc,
-                     hist_i, hist_f, jnp.bool_(False)))
+                     jnp.int32(0), hist_i, hist_f, jnp.bool_(False)))
             with jax.named_scope("converge"):
                 conv = state_lib.converged_device(psd, t2)
             return (it, values, psd, dmax, calm, counts, hslots, sbacc,
-                    hist_i, hist_f, conv)
+                    hist_i, hist_f, conv, miss)
 
         fn = jax.jit(chunk_traced,
                      donate_argnums=(2, 3, 4, 5, 6, 7, 8, 14, 15))
@@ -1364,6 +1463,8 @@ class StructureAwareEngine:
         the widths warmed."""
         for wb in self._ladder:
             self._get_chunk(wb)(*self._idle_chunk_args(wb))
+        if self.spill is not None:
+            self.spill.prewarm()
         return list(self._ladder)
 
     def compiled_chunk_text(self, width: int | None = None) -> str:
@@ -1380,10 +1481,33 @@ class StructureAwareEngine:
         compiled fused chunk of every dispatch bucket, keyed by
         instruction name and result shape as a profiler trace prints them
         (:func:`repro.obs.scopes.op_key`). Compiles each bucket again, so
-        it belongs after any timed work."""
-        return obs_scopes.merge(
-            obs_scopes.op_scopes(self.compiled_chunk_text(wb))
-            for wb in self._ladder)
+        it belongs after any timed work. Under an out-of-core budget the
+        tier's placement and compaction programs (scope ``ooc_place``)
+        are included."""
+        texts = [self.compiled_chunk_text(wb) for wb in self._ladder]
+        if self.spill is not None:
+            texts += self._pool_texts()
+        return obs_scopes.merge(obs_scopes.op_scopes(t) for t in texts)
+
+    def _pool_texts(self) -> list[str]:
+        """Compiled program text of the out-of-core placement and move
+        calls, each at its one chunk shape."""
+        self.spill.prewarm()
+        ed = self._ed
+        arrays = (ed.src, ed.dstl, ed.w, ed.valid, ed.cov)
+
+        def idx(k):
+            return jax.ShapeDtypeStruct((k,), jnp.int32)
+
+        texts = [self._fns["pool_move"].lower(
+            *arrays, idx(self._MOVE_CHUNK), idx(self._MOVE_CHUNK))
+            .compile().as_text()]
+        for k in self._PLACE_CHUNKS:
+            pay = [jax.ShapeDtypeStruct((k,) + a.shape[1:], a.dtype)
+                   for a in arrays]
+            texts.append(self._fns[f"pool_place_{k}"].lower(
+                *arrays, idx(k), *pay).compile().as_text())
+        return texts
 
     # -- main loop ----------------------------------------------------------
     def run(self, max_iterations: int | None = None,
@@ -1400,13 +1524,15 @@ class StructureAwareEngine:
         (``RunResult.timeline``) and emits run/chunk/repartition spans +
         superstep counters into the installed :mod:`repro.obs` recorder.
         ``None`` (default) auto-enables tracing exactly when a recorder
-        is installed, so long-lived callers (streaming reconvergence,
-        serve lanes' sibling engines) inherit the capture without
-        plumbing. Values and every algorithmic counter of a traced run
-        are bitwise identical to the untraced one (property-tested)."""
+        is installed (and asks for the timeline), so long-lived callers
+        (streaming reconvergence, serve lanes' sibling engines) inherit
+        the capture without plumbing. Values and every algorithmic
+        counter of a traced run are bitwise identical to the untraced one
+        (property-tested)."""
         fused = self.config.fused if fused is None else fused
         if trace is None:
-            trace = obs_trace.current() is not None
+            rec = obs_trace.current()
+            trace = rec is not None and rec.timeline
         with obs_trace.span("run", cat="engine", fused=bool(fused),
                             warm=warm is not None) as sp:
             res = (self._run_fused(max_iterations, warm, trace=trace)
@@ -1529,7 +1655,7 @@ class StructureAwareEngine:
                         cap = _hist_cap(it_end - it)
                         chunk = self._get_chunk(wb, cap)
                         (it_dev, values, psd, dmax, calm, counts, hslots,
-                         sbacc, hist_i, hist_f, conv) = chunk(
+                         sbacc, hist_i, hist_f, conv, miss) = chunk(
                             self._ed, self._coupling_dev, values, psd,
                             dmax, calm,
                             jnp.zeros(p.num_blocks, jnp.int32),
@@ -1544,7 +1670,7 @@ class StructureAwareEngine:
                     else:
                         chunk = self._get_chunk(wb)
                         (it_dev, values, psd, dmax, calm, counts, hslots,
-                         sbacc, conv) = chunk(
+                         sbacc, conv, miss) = chunk(
                             self._ed, self._coupling_dev, values, psd,
                             dmax, calm,
                             jnp.zeros(p.num_blocks, jnp.int32),
@@ -1567,6 +1693,8 @@ class StructureAwareEngine:
                         if trace:
                             hi = syncs.read(hist_i)[:it_new - it]
                             hf = syncs.read(hist_f)[:it_new - it]
+                        if spill is not None:
+                            spill.check_sweeps(int(syncs.read(miss)), it)
                     csp.set(it_end=it_new)
                 # host work from the end of the reads to the next chunk's
                 # dispatch: counters, history, repartition, bucket pick

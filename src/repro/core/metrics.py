@@ -81,14 +81,17 @@ class Metrics:
     subblocks_retired: int = 0  # sub-blocks retired at end (calm >= limit)
     mean_subblock_dispatch: float = 0.0  # live sub-blocks per block load
     # out-of-core residency accounting (all zero when the run is fully
-    # resident — resident_blocks unset or >= P). These audit the spill
-    # tier's traffic; they are NOT part of the algorithmic trajectory, so
-    # the budget-vs-resident bitwise parity tests exclude them.
+    # resident — resident_rows unset or >= the plan's rows). These audit
+    # the spill tier's traffic; they are NOT part of the algorithmic
+    # trajectory, so the budget-vs-resident bitwise parity tests exclude
+    # them.
     spill_evictions: int = 0  # blocks evicted device -> spill tier
     bytes_spilled: int = 0  # tile-row bytes moved off-device
     prefetch_hits: int = 0  # scheduled-block demands already resident
     prefetch_misses: int = 0  # demand fetches the prefetcher missed
     bytes_fetched: int = 0  # tile-row bytes scattered back on demand/prefetch
+    compactions: int = 0  # out-of-core pool compactions
+    rows_moved: int = 0  # pool rows moved by those compactions
     host_syncs: int = 0  # blocking device->host reads (see HostSyncs)
 
     @property

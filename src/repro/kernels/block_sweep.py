@@ -45,7 +45,7 @@ from jax.experimental import pallas as pl
 from repro.analysis.contracts import one_executable_per
 from repro.obs.scopes import gather
 
-# one sweep callable per (program, tile geometry, mode): the engines build
+# one sweep callable per (program, run lengths, mode): the engines build
 # their processors once per epoch, and repeated builds (prewarm, contract
 # probes) must not mint fresh closures or the jit caches downstream refill
 _BUILDER_CACHE: dict = {}
@@ -211,11 +211,13 @@ def _cache_put(key, sweep):
 
 
 @one_executable_per("program", "tile geometry", "subblocks", "lanes")
-def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
-                     tile_w: int, block_size: int, n_total: int,
-                     subblocks: int = 1, lanes: bool = False,
+def make_block_sweep(program, tile_cnt, *, tile_w: int, block_size: int,
+                     n_total: int, subblocks: int = 1, lanes: bool = False,
                      interpret: bool = True):
-    """Build the block sweep for one program over one tile geometry.
+    """Build the block sweep for one program over one tile geometry: the
+    blocks' run lengths ``tile_cnt``. Where each run starts is read from
+    ``ed.start`` at run time, so one sweep serves the plan's layout and an
+    out-of-core pool alike.
 
     Returns ``sweep(ed, values, row[, sub_act])`` — or
     ``sweep(ed, values, vconst, row[, sub_act])`` with ``lanes=True`` —
@@ -226,13 +228,13 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
     caches stay warm.
 
     The block's tile run is walked in chunks of ``K`` rows
-    (:data:`CHUNK_TILES`, or the storage's row count if that is smaller)
+    (:data:`CHUNK_TILES`, or the row arrays' row count if that is smaller)
     by an XLA loop whose trip count is the block's own
     ``ceil(tile_cnt / K)``. Each step reads every row array as one
     contiguous slice of ``K`` rows starting at the chunk's first row; a
-    window that would run past the storage's end is shifted back to end
-    at its last row, and the rows it shifts over (the chunk before, or
-    an earlier block's) are masked. It then gathers the chunk's source
+    window that would run past the arrays' end is shifted back to end
+    at their last row, and the rows it shifts over (the chunk before, or
+    another block's) are masked. It then gathers the chunk's source
     values and applies ``edge_map`` in XLA (a vertex-array gather has no
     Mosaic lowering), masks invalid slots, rows outside the chunk's part
     of the block's run and — with ``subblocks > 1`` — rows whose covered
@@ -244,37 +246,35 @@ def make_block_sweep(program, tile_start, tile_cnt, *, n_tiles: int,
     each ``sum`` slot is within :func:`sum_tolerance` of the scatter's
     (bitwise under the interpreter, which sums in edge order).
     """
-    ts = np.asarray(tile_start, dtype=np.int32)
     tc = np.asarray(tile_cnt, dtype=np.int32)
-    key = (program, ts.tobytes(), tc.tobytes(), int(n_tiles), int(tile_w),
-           int(block_size), int(n_total), int(subblocks), bool(lanes),
-           bool(interpret))
+    key = (program, tc.tobytes(), int(tile_w), int(block_size),
+           int(n_total), int(subblocks), bool(lanes), bool(interpret))
     cached = _BUILDER_CACHE.get(key)
     if cached is not None:
         return cached
 
     c = block_size
     tile = tile_w
-    # a storage of fewer rows than a chunk is read in one window of them all
-    k = min(CHUNK_TILES, int(n_tiles))
     masked = subblocks > 1
     is_sum = program.combine == "sum"
     ident = float(program.identity)
-    t0_d = jnp.asarray(ts)
     tc_d = jnp.asarray(tc)
     combine = functools.partial(_combine_call, combine=program.combine,
                                 identity=ident, interpret=interpret)
 
     def block_agg(ed, values, row, sub_act):
         nl = values.shape[1] if lanes else 1
-        t0, cnt = t0_d[row], tc_d[row]
+        t0, cnt = ed.start[row], tc_d[row]
         agg0 = jnp.full((nl, c), 0.0 if is_sum else ident, jnp.float32)
+        n_tiles = int(ed.src.shape[0])
         if n_tiles == 0:
             return agg0
+        # arrays of fewer rows than a chunk are read in one window of all
+        k = min(CHUNK_TILES, n_tiles)
 
         def chunk(i, agg):
-            # rows [s0, s0 + k): a window past the storage's end is
-            # shifted back to end at its last row (computed here, as
+            # rows [s0, s0 + k): a window past the arrays' end is
+            # shifted back to end at their last row (computed here, as
             # dynamic_slice would clamp it silently), and the rows it
             # shifts over are masked like rows past the block's run
             s = t0 + i * k

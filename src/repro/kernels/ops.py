@@ -40,13 +40,13 @@ def edge_block_max(msg: jnp.ndarray, dst: jnp.ndarray, block_size: int,
 
 def make_block_sweep(program, store, block_size: int, n_total: int, *,
                      subblocks: int = 1, lanes: bool = False):
-    """Build the per-block sweep over ``store``'s tile geometry: gather and
+    """Build the per-block sweep over ``store``'s tile geometry (its run
+    lengths; each run's start is read from ``ed.start``): gather and
     ``edge_map`` in XLA, the segmented combine in one ``pallas_call`` per
     chunk of tile rows, ``apply`` in XLA. See
     :mod:`repro.kernels.block_sweep`."""
     return _bs.make_block_sweep(
-        program, store.tile_start, store.tile_cnt,
-        n_tiles=int(store.src.shape[0]), tile_w=int(store.src.shape[1]),
+        program, store.tile_cnt, tile_w=int(store.src.shape[1]),
         block_size=block_size, n_total=n_total, subblocks=subblocks,
         lanes=lanes, interpret=_interpret())
 

@@ -31,6 +31,10 @@ SCOPES = (
     "converge",  # the convergence test
     "chunk_loop",  # the chunk's own loop control (superstep count, stop)
 )
+# named scopes of the programs the out-of-core tier runs between chunks
+TIER_SCOPES = (
+    "ooc_place",  # pool writes: block placement and compaction moves
+)
 UNSCOPED = "unscoped"
 
 
@@ -86,11 +90,12 @@ def _operands(line: str) -> list[str]:
 
 
 def scope_of(op_name: str) -> str:
-    """The innermost of :data:`SCOPES` on an ``op_name`` path; a scope
-    under a transform reads ``vmap(sweep_fold)`` and counts as well."""
+    """The innermost of :data:`SCOPES` (or :data:`TIER_SCOPES`) on an
+    ``op_name`` path; a scope under a transform reads
+    ``vmap(sweep_fold)`` and counts as well."""
     for part in reversed(op_name.split("/")):
         name = part[part.rfind("(") + 1:].rstrip(")")
-        if name in SCOPES:
+        if name in SCOPES or name in TIER_SCOPES:
             return name
     return UNSCOPED
 

@@ -116,8 +116,13 @@ class TraceRecorder:
     ``ts``/``dur`` are seconds relative to the recorder epoch.
     """
 
-    def __init__(self, capacity: int = DEFAULT_CAPACITY):
+    def __init__(self, capacity: int = DEFAULT_CAPACITY,
+                 timeline: bool = True):
         self.capacity = int(capacity)
+        # whether engines that find this recorder installed also record
+        # the device's per-superstep timeline (the traced chunk variant);
+        # spans are recorded either way
+        self.timeline = bool(timeline)
         self.events: deque = deque(maxlen=self.capacity)
         self.dropped = 0  # oldest events evicted by the ring
         self._epoch = time.perf_counter()
@@ -187,9 +192,11 @@ def current() -> TraceRecorder | None:
 
 
 @contextmanager
-def recording(capacity: int = DEFAULT_CAPACITY):
+def recording(capacity: int = DEFAULT_CAPACITY, timeline: bool = True):
     """Install a fresh recorder for the duration of the block (restoring
     whatever was installed before): the test/bench-friendly entry point.
+    ``timeline=False`` records spans only: engines keep their untraced
+    chunks (no per-superstep history buffers).
 
     >>> with recording() as rec:
     ...     engine.run()
@@ -197,7 +204,7 @@ def recording(capacity: int = DEFAULT_CAPACITY):
     """
     global _CURRENT
     prev = _CURRENT
-    rec = TraceRecorder(capacity)
+    rec = TraceRecorder(capacity, timeline)
     _CURRENT = rec
     try:
         yield rec

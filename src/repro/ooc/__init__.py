@@ -2,13 +2,12 @@
 
 Three pieces, layered under the existing engines:
 
-  * :mod:`repro.ooc.store` — :class:`SpillStore`: per-block residency over
-    the unified tiled layout. Device memory is modeled as a fixed budget
-    of resident block slots (``EngineConfig.resident_blocks``); cold
-    blocks' edge tile rows are evicted to a host cache / npz disk
-    segments and demand-fetched back before the schedule can touch them,
-    so a budget-constrained run is bitwise-identical to the fully
-    resident one.
+  * :mod:`repro.ooc.store` — :class:`SpillStore`: the device holds a pool
+    of ``EngineConfig.resident_rows`` edge tile rows, each resident
+    block's run contiguous in it at a traced row start; cold blocks'
+    rows live in a host cache / npz disk segments and are placed back
+    before the schedule can touch them, so a budget-constrained run is
+    bitwise-identical to the fully resident one.
   * :mod:`repro.ooc.prefetch` — the activity-directed policy: the PSD
     priority queue predicts the next superstep's schedule (the host
     scheduler twin is property-tested decision-identical to the fused

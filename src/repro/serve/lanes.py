@@ -304,9 +304,13 @@ class LaneEngine:
         lane_shape = ((p.num_blocks, n_lanes) if sb == 1
                       else (p.num_blocks, sb, n_lanes))
         calm_shape = (p.num_blocks,) if sb == 1 else (p.num_blocks, sb)
+        # under an out-of-core budget the lanes read pinned whole-plan
+        # copies, not the live pool: compile for that shape
+        ed = (eng.edge_snapshot() if eng.spill is not None
+              else eng.edge_state)
         for wb in eng._ladder:
             fn = self._get_chunk(wb)
-            fn(eng.edge_state, jnp.zeros(eng._coupling.shape, jnp.float32),
+            fn(ed, jnp.zeros(eng._coupling.shape, jnp.float32),
                jnp.zeros((vl, n_lanes), jnp.float32),
                jnp.zeros((vl, n_lanes), jnp.float32),
                jnp.zeros(lane_shape, jnp.float32),

@@ -28,13 +28,14 @@ only mutate edge data in place re-enter the already-compiled lane
 superstep; only a tile-overflow plan rebuild recompiles — exactly the
 streaming engine's own compile story.
 
-Out-of-core budgets (``EngineConfig.resident_blocks``): pinned epochs
-survive eviction. The spill tier's pre-eviction hook preserves every
-live pin before the eviction scatter invalidates device rows, and a pin
-taken while blocks are already spilled materializes the holes from the
-tier's truth (``StreamingEngine.snapshot`` / ``EpochState.ed``) — so
-lane batches always read a complete, consistent edge state even when the
-live engine holds only a fraction of the graph resident.
+Out-of-core budgets (``EngineConfig.resident_rows``): pinned epochs
+survive eviction. The live engine's row arrays are a pool of
+``resident_rows`` tile rows, so a pin is always a whole-plan copy built
+from the pool and the spill tier's truth (``StreamingEngine.snapshot`` /
+``EpochState.ed``), and the tier's pre-eviction hook preserves every
+live pin before a freed range can be overwritten — so lane batches
+always read a complete, consistent edge state even when the live engine
+holds only a fraction of the graph resident.
 """
 from __future__ import annotations
 

@@ -171,12 +171,12 @@ class EpochState:
     @property
     def ed(self) -> EdgeData:
         if self._ed is None:
-            spill = self.engine.spill
-            if spill is not None and spill.spilled_blocks.size:
-                # safety net: never hand out a live view with spilled
-                # holes — materialize a self-contained copy instead. The
-                # eager paths (snapshot() under spill, the eviction hook,
-                # the ingest preamble) normally preserve before this fires.
+            if self.engine.spill is not None:
+                # safety net: never hand out the live pool (a part of the
+                # plan's rows, at pool starts) — build a whole-plan copy
+                # instead. The eager paths (snapshot() under spill, the
+                # eviction hook, the ingest preamble) normally preserve
+                # before this fires.
                 self.preserve()
         return self._ed if self._ed is not None else self.engine.edge_state
 
@@ -225,13 +225,12 @@ class StreamingEngine:
                 coupling_counts=self.W.copy(),
                 out_deg=self.out_deg.copy(), in_deg=self.in_deg.copy(),
                 edge_counts=np.array(self.engine.edge_counts))
-            spill = self.engine.spill
-            if spill is not None and spill.spilled_blocks.size:
-                # under an out-of-core budget the live edge state already
-                # has spilled holes: preserve now (edge_snapshot
-                # materializes the holes from the spill tier), instead of
-                # lazily at the next ingest — the pin must be readable
-                # before then
+            if self.engine.spill is not None:
+                # under an out-of-core budget the live edge state is a
+                # pool holding part of the plan's rows: preserve now
+                # (edge_snapshot builds the whole plan from the pool and
+                # the spill tier), instead of lazily at the next ingest —
+                # the pin must be readable before then
                 es.preserve()
                 self.metrics.snapshots_preserved += 1
             self._snapshots.append(weakref.ref(es))
@@ -262,11 +261,11 @@ class StreamingEngine:
 
     def _on_spill_evict(self) -> None:
         """Spill-tier pre-eviction hook: pinned epochs must survive the
-        eviction of their blocks. The eviction scatter really invalidates
-        the device rows, so every live pin is preserved first —
-        ``edge_snapshot`` materializes any already-spilled holes from the
-        tier's truth, and the about-to-be-evicted rows are still resident
-        at hook time."""
+        eviction of their blocks. An evicted block's pool range is
+        overwritten by the next placement, so every live pin is preserved
+        first — ``edge_snapshot`` builds the whole plan from the pool and
+        the tier's truth, and the about-to-be-evicted rows are still
+        resident at hook time."""
         self.metrics.snapshots_preserved += self._preserve_pinned()
 
     # -- epoch management ----------------------------------------------------
@@ -303,10 +302,10 @@ class StreamingEngine:
         spill = self.engine.spill
         if spill is not None:
             # the host tile mirror is the truth under streaming mutation:
-            # evictions never need a device readback and fetches re-scatter
-            # CURRENT truth even for blocks mutated while spilled (an
-            # ingest commit to a non-resident block is harmless — the
-            # fetch overwrites its rows wholesale)
+            # evictions never need a device readback and fetches place
+            # CURRENT truth even for blocks mutated while spilled (a
+            # commit writes a resident block's rows where its run sits in
+            # the pool, and a spilled block's only into this mirror)
             spill.row_source = self.tiles.rows2d
             spill.on_evict = self._on_spill_evict
 
@@ -379,7 +378,7 @@ class StreamingEngine:
         is the measured warm-vs-cold restart win (``initial_result``
         carries its metrics); ``verify=False`` trusts the checkpoint and
         skips the run. A checkpoint written under one residency budget
-        restores under any other (``config.resident_blocks`` applies to
+        restores under any other (``config.resident_rows`` applies to
         the NEW engine)."""
         from repro.ooc.snapshot import GraphCheckpoint
         if not isinstance(ckpt, GraphCheckpoint):

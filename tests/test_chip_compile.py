@@ -6,7 +6,8 @@ shape (n = 2^20 vertices, 512-wide tiles, 256-vertex blocks, 8 lanes),
 which catches what the Pallas interpreter cannot — tiling rules, memory
 spaces, unsupported relayouts. Each compiled program must contain the
 kernel (``tpu_custom_call``), so the sweep neither fell back to XLA nor
-compiled in interpret mode.
+compiled in interpret mode. Each run's first row is read from the traced
+row-start table ``ed.start``, as an out-of-core pool needs.
 
 The topology is described inside a module fixture, never while a module
 is imported: the description loads the TPU library, which only one
@@ -33,7 +34,6 @@ P = N // BLOCK
 # and a long tail of 1-3 tile blocks (the shape powerlaw_graph produces)
 TILE_CNT = np.concatenate([[24478], 1 + np.arange(P - 1) % 3]).astype(
     np.int32)
-TILE_START = np.concatenate([[0], np.cumsum(TILE_CNT)[:-1]]).astype(np.int32)
 N_TILES = int(TILE_CNT.sum())
 
 VARIANTS = {
@@ -92,15 +92,16 @@ def compiled_sweep(one_chip, no_cache):
 def _compile_sweep(variant, form, one_chip):
     factory, lanes, subblocks = VARIANTS[variant]
     sweep = make_block_sweep(
-        factory(), TILE_START, TILE_CNT, n_tiles=N_TILES, tile_w=TILE,
-        block_size=BLOCK, n_total=N, subblocks=subblocks, lanes=lanes,
-        interpret=False)
+        factory(), TILE_CNT, tile_w=TILE, block_size=BLOCK, n_total=N,
+        subblocks=subblocks, lanes=lanes, interpret=False)
     ed = EdgeData(src=_spec((N_TILES, TILE), jnp.int32, one_chip),
                   dstl=_spec((N_TILES, TILE), jnp.int32, one_chip),
                   w=_spec((N_TILES, TILE), jnp.float32, one_chip),
                   valid=_spec((N_TILES, TILE), jnp.bool_, one_chip),
                   cov=_spec((N_TILES, subblocks), jnp.bool_, one_chip),
-                  aux=_spec((N,), jnp.float32, one_chip))
+                  aux=_spec((N,), jnp.float32, one_chip),
+                  start=_spec((P,), jnp.int32, one_chip),
+                  resident=_spec((P,), jnp.bool_, one_chip))
     vshape = (N, LANES) if lanes else (N,)
     vals = _spec(vshape, jnp.float32, one_chip)
     masked = subblocks > 1
@@ -177,3 +178,47 @@ def test_chunk_scopes_for_v5e(one_chip, no_cache, monkeypatch):
         key = scopes.op_key(line)
         if key in got and got[key] == scopes.UNSCOPED:
             assert any(op in line for op in idle), line[:200]
+
+
+def test_pool_chunk_compiles_for_v5e(one_chip, no_cache, monkeypatch):
+    """An engine under an out-of-core budget: its fused chunk reads every
+    run through the traced row-start table of a pool smaller than the
+    plan, still calls the kernel and still slices its tile rows, and the
+    pool's placement and compaction writes compile under ``ooc_place``."""
+    from repro.core import graph as G
+    from repro.core.engine import EngineConfig, StructureAwareEngine
+    from repro.kernels import ops as kops
+    from repro.obs import scopes
+
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    g = G.powerlaw_graph(4096, avg_deg=8, seed=0, weighted=True)
+    cnt = StructureAwareEngine(g, A.pagerank(), EngineConfig(
+        t2=1e-6)).plan.unified.tile_cnt
+    rows = int(np.sort(cnt)[::-1][:10].sum()) + 2
+    assert rows < int(cnt.sum())
+    eng = StructureAwareEngine(g, A.pagerank(), EngineConfig(
+        t2=1e-6, use_pallas=True, resident_rows=rows))
+    assert eng.edge_state.src.shape[0] == rows
+    wb = eng._ladder[0]
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    args = jax.tree.map(spec, eng._idle_chunk_args(wb))
+    text = eng._get_chunk(wb).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [line for line in text.splitlines()
+                if re.search(r"= \S+ gather\(", line)
+                and scopes.scope_of(_op_name(line)) == "sweep_gather_rows"]
+    eng.spill.prewarm()
+    ed = eng.edge_state
+    arrays = [spec(a) for a in (ed.src, ed.dstl, ed.w, ed.valid, ed.cov)]
+    for key, k in (("pool_move", eng._MOVE_CHUNK),
+                   *((f"pool_place_{k}", k) for k in eng._PLACE_CHUNKS)):
+        idx = _spec((k,), jnp.int32, one_chip)
+        more = ([idx] if key == "pool_move" else
+                [_spec((k,) + a.shape[1:], a.dtype, one_chip)
+                 for a in arrays])
+        got = scopes.op_scopes(eng._fns[key].lower(
+            *arrays, idx, *more).compile().as_text())
+        assert "ooc_place" in got.values(), key

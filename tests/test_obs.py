@@ -118,6 +118,40 @@ def test_run_trace_autodetects_installed_recorder():
     assert eng.run().timeline is None  # uninstalled again
 
 
+def test_spans_only_recorder_keeps_the_untraced_chunk():
+    """A recorder installed with ``timeline=False`` records the engine's
+    spans, but the run stays on the untraced chunk: no timeline, no
+    counter rows, values and counters bitwise the untraced run's."""
+    g = G.uniform_graph(200, deg=4, seed=0, weighted=True)
+    eng = StructureAwareEngine(g, A.pagerank(), CFG)
+    plain = eng.run()
+    with obs_trace.recording(timeline=False) as rec:
+        res = eng.run()
+    assert res.timeline is None
+    assert not any(e["type"] == "counter" for e in rec.events)
+    assert {("engine", "run"), ("engine", "chunk")} <= {
+        (e["cat"], e["name"]) for e in rec.events}
+    assert not any(k[1] == 16 for k in eng._fns if k[0] == "chunk")
+    assert np.array_equal(res.values, plain.values)
+    assert res.metrics.iterations == plain.metrics.iterations
+
+
+def test_op_scopes_name_the_pool_writes():
+    """Under an out-of-core budget ``op_scopes`` also names the tier's
+    placement and compaction writes, by ``ooc_place``."""
+    from repro.obs import scopes
+    g = G.powerlaw_graph(1500, avg_deg=6, seed=3, weighted=True)
+    cfg = EngineConfig(t2=1e-9, width=4, block_size=128)
+    cnt = StructureAwareEngine(g, A.pagerank(), cfg).plan.unified.tile_cnt
+    eng = StructureAwareEngine(g, A.pagerank(), EngineConfig(
+        **{**cfg.__dict__,
+           "resident_rows": int(np.sort(cnt)[::-1][:6].sum()) + 1}))
+    got = eng.op_scopes()
+    assert "ooc_place" in got.values()
+    assert set(got.values()) <= set(scopes.SCOPES) | set(
+        scopes.TIER_SCOPES) | {scopes.UNSCOPED}
+
+
 # -- as_dict / @property parity ----------------------------------------------
 @pytest.mark.parametrize("cls", [Metrics, StreamMetrics, ServeMetrics])
 def test_every_property_lands_in_as_dict(cls):
