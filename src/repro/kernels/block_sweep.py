@@ -7,7 +7,11 @@ scatters (``make_tiled_processor`` / ``make_lane_processor``).
 ``make_block_sweep`` keeps the gather and ``edge_map`` in XLA, because a
 gather from the whole vertex array has no Mosaic lowering, and moves the
 segmented combine into one ``pallas_call`` per chunk of
-:data:`CHUNK_TILES` tile rows:
+:data:`CHUNK_TILES` tile rows. Where ``edge_map`` reads its source's aux
+and ignores the edge weight ``w`` (PageRank, the PPR lanes), it runs once
+per vertex before the chunk loop and each edge gathers one message; else
+each edge gathers its source's value (and aux) and maps them. The
+combine:
 
 - ``sum`` — a one-hot matmul on the MXU, ``(L, TILE) @ (TILE, C)`` per
   tile row at ``Precision.HIGHEST``, single-lane (L = 1) and
@@ -19,7 +23,7 @@ segmented combine into one ``pallas_call`` per chunk of
 Per chunk the kernel reads ``K * L * TILE`` messages, ``K * TILE`` slot
 offsets and the ``(L, C)`` aggregate, and writes the aggregate back; the
 XLA side reads each row array (src, dst, w, valid, and cov when masked)
-as one contiguous ``(K, TILE)`` slice and gathers the source values. The
+as one contiguous ``(K, TILE)`` slice and gathers once per edge. The
 chunk loop runs the block's own ``ceil(tile_cnt / K)`` steps, so a block
 costs its true edge count, not the largest block's.
 
@@ -210,6 +214,25 @@ def _cache_put(key, sweep):
     _BUILDER_CACHE[key] = sweep
 
 
+def _vertex_message(program, n: int, lanes: bool) -> bool:
+    """Whether ``program.edge_map``'s message is its source vertex's
+    alone: its trace on vertex-shaped inputs reads ``src_aux`` and never
+    reads ``w``. ``edge_map`` is ``@elementwise``, so then
+    ``edge_map(values, aux, _)[src]`` is ``edge_map(values[src],
+    aux[src], _)`` bit for bit, and the sweep maps once per vertex and
+    gathers one message per edge in place of a value and an aux."""
+    f32 = jnp.float32
+    vec = jax.ShapeDtypeStruct((n,), f32)
+    vals = jax.ShapeDtypeStruct((n, 8), f32) if lanes else vec  # any L
+    jaxpr = jax.make_jaxpr(program.edge_map)(vals, vec, vec).jaxpr
+    _, aux, w = jaxpr.invars
+
+    def reads(var):
+        return any(v is var for e in jaxpr.eqns for v in e.invars) or any(
+            v is var for v in jaxpr.outvars)
+    return reads(aux) and not reads(w)
+
+
 @one_executable_per("program", "tile geometry", "subblocks", "lanes")
 def make_block_sweep(program, tile_cnt, *, tile_w: int, block_size: int,
                      n_total: int, subblocks: int = 1, lanes: bool = False,
@@ -234,11 +257,14 @@ def make_block_sweep(program, tile_cnt, *, tile_w: int, block_size: int,
     contiguous slice of ``K`` rows starting at the chunk's first row; a
     window that would run past the arrays' end is shifted back to end
     at their last row, and the rows it shifts over (the chunk before, or
-    another block's) are masked. It then gathers the chunk's source
-    values and applies ``edge_map`` in XLA (a vertex-array gather has no
-    Mosaic lowering), masks invalid slots, rows outside the chunk's part
-    of the block's run and — with ``subblocks > 1`` — rows whose covered
-    sub-ranges are all inactive to the identity, and hands the chunk to
+    another block's) are masked. It then gathers the chunk's messages in
+    XLA (a vertex-array gather has no Mosaic lowering): where
+    ``edge_map`` reads aux and not ``w`` (:func:`_vertex_message`, decided
+    from its trace), the message ``edge_map`` made once per vertex before
+    the loop; else the source values and aux, mapped per edge. It masks
+    invalid slots, rows outside the chunk's part of the block's run and
+    — with ``subblocks > 1`` — rows whose covered sub-ranges are all
+    inactive to the identity, and hands the chunk to
     :func:`_combine_kernel`. ``apply`` runs in XLA on the block's
     aggregate.
 
@@ -261,6 +287,7 @@ def make_block_sweep(program, tile_cnt, *, tile_w: int, block_size: int,
     tc_d = jnp.asarray(tc)
     combine = functools.partial(_combine_call, combine=program.combine,
                                 identity=ident, interpret=interpret)
+    per_vertex = _vertex_message(program, n_total, lanes)
 
     def block_agg(ed, values, row, sub_act):
         nl = values.shape[1] if lanes else 1
@@ -271,6 +298,15 @@ def make_block_sweep(program, tile_cnt, *, tile_w: int, block_size: int,
             return agg0
         # arrays of fewer rows than a chunk are read in one window of all
         k = min(CHUNK_TILES, n_tiles)
+        if per_vertex:
+            # once per vertex, outside the chunk loop, so that XLA cannot
+            # fuse the map into the gather and read aux per edge again;
+            # values may be padded past the vertices, aux is not, and w is
+            # unread
+            with jax.named_scope("sweep_src_map"):
+                nv = ed.aux.shape[0]
+                src_msg = program.edge_map(
+                    values[:nv], ed.aux, jnp.zeros(nv, ed.w.dtype))
 
         def chunk(i, agg):
             # rows [s0, s0 + k): a window past the arrays' end is
@@ -297,14 +333,17 @@ def make_block_sweep(program, tile_cnt, *, tile_w: int, block_size: int,
             def accumulate(agg):
                 ok = valid & live[:, None]
                 idx = src.reshape(-1) if lanes else src
-                vals = gather("sweep_gather_values", values, idx)
-                aux = gather("sweep_gather_aux", ed.aux, idx)
+                if per_vertex:
+                    msg = gather("sweep_gather_values", src_msg, idx)
+                else:
+                    msg = program.edge_map(
+                        gather("sweep_gather_values", values, idx),
+                        gather("sweep_gather_aux", ed.aux, idx),
+                        w.reshape(-1) if lanes else w)
                 if lanes:
-                    msg = program.edge_map(vals, aux, w.reshape(-1))
                     msg = jnp.where(ok.reshape(-1, 1), msg, ident)
                     msg = msg.reshape(k, tile, nl).transpose(0, 2, 1)
                 else:
-                    msg = program.edge_map(vals, aux, w)
                     msg = jnp.where(ok, msg, ident)[:, None, :]
                 msg = msg.astype(jnp.float32)
                 with jax.named_scope("sweep_fold"):

@@ -18,8 +18,9 @@ import jax
 
 # the named scopes of the fused chunk, innermost first where they nest
 SCOPES = (
-    "sweep_gather_values",  # source values of a chunk of tile rows
-    "sweep_gather_aux",  # the sources' aux values
+    "sweep_gather_values",  # source values (or messages) of a chunk
+    "sweep_gather_aux",  # the sources' aux values, where gathered per edge
+    "sweep_src_map",  # edge_map once per vertex, where w is unread
     "sweep_gather_rows",  # the chunk's tile rows (src, dst, w, valid, cov)
     "sweep_fold",  # the segmented combine (Pallas call or XLA scatter)
     "sweep_apply",  # the vertex update on the block's aggregate

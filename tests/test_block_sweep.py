@@ -18,8 +18,10 @@ supersteps against 478 dense at scale 16). The ``mxu_sum`` tests run
 that form through the engines under the interpreter: values within the
 stated tolerance, counters bounded.
 """
+import dataclasses
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -27,8 +29,9 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core import algorithms as A
 from repro.core import graph as G
-from repro.core.engine import (EngineConfig, StructureAwareEngine,
-                               coupling_from_counts)
+from repro.core.engine import (EdgeData, EngineConfig,
+                               StructureAwareEngine, coupling_from_counts,
+                               tile_coverage)
 from repro.kernels import ops, ref
 from repro.kernels.block_sweep import CHUNK_TILES, sum_tolerance
 from repro.serve.lanes import LaneEngine
@@ -299,3 +302,130 @@ def test_service_use_pallas_plumbing():
     for rd, rp in zip(results[False], results[True]):
         assert np.array_equal(rd.values, rp.values)
         assert rd.iterations == rp.iterations
+
+
+# -- the message mapped once per vertex (edge_map that ignores w) -------------
+def _pagerank_reading_w():
+    """PageRank whose ``edge_map`` reads ``w`` (and adds nothing for the
+    benchmark's finite weights): the decision follows the trace, not the
+    program's name."""
+    base = A.pagerank()
+
+    def edge_map(src_val, src_aux, w):
+        return src_val / src_aux + 0.0 * w
+    return dataclasses.replace(base, edge_map=edge_map)
+
+
+# name: (program factory, lanes, maps once per vertex)
+_SRC_MAP = {
+    "pagerank": (A.pagerank, False, True),
+    "ppr_lanes": (A.k_personalized_pagerank, True, True),
+    "sssp": (lambda: A.sssp(0), False, False),
+    "k_sssp": (A.k_source_sssp, True, False),
+    "pagerank_reads_w": (_pagerank_reading_w, False, False),
+}
+
+
+def _scope_names(jaxpr):
+    """The innermost named scope (``repro.obs.scopes.scope_of``) of each
+    of ``jaxpr``'s equations, nested bodies (loops, conds, the Pallas
+    kernel) included."""
+    from repro.obs.scopes import scope_of
+    out = set()
+    for eqn in jaxpr.eqns:
+        out.add(scope_of(str(eqn.source_info.name_stack)))
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out |= _scope_names(sub)
+    return out
+
+
+def _pool_layout(store, aux, subblocks, block_size):
+    """The plan's runs laid out in reverse block order behind two free
+    rows, ``start`` pointing at each: an out-of-core pool's row-start
+    table."""
+    runs = [np.arange(t0, t0 + n)
+            for t0, n in zip(store.tile_start, store.tile_cnt)]
+    order = np.concatenate([np.arange(2)] + runs[::-1]).astype(np.int64)
+    start = np.zeros(len(runs), np.int32)
+    at = 2
+    for b in range(len(runs))[::-1]:
+        start[b] = at
+        at += len(runs[b])
+    src, dstl, w, valid = (np.asarray(a)[order] for a in (
+        store.src, store.dst_local, store.w, store.valid))
+    valid[:2] = False  # the free rows hold nothing
+    return EdgeData(
+        src=jnp.asarray(src), dstl=jnp.asarray(dstl), w=jnp.asarray(w),
+        valid=jnp.asarray(valid),
+        cov=jnp.asarray(tile_coverage(dstl, valid, subblocks, block_size)),
+        aux=aux, start=jnp.asarray(start),
+        resident=jnp.ones(len(runs), bool))
+
+
+@pytest.mark.parametrize("layout", ["plan", "pool"])
+@pytest.mark.parametrize("subblocks", [1, 8])
+@pytest.mark.parametrize("prog", sorted(_SRC_MAP))
+def test_sweep_maps_message_per_vertex(prog, subblocks, layout,
+                                       monkeypatch):
+    """Where ``edge_map`` reads aux and never ``w`` the sweep maps once per
+    vertex under ``sweep_src_map`` and gathers the message: its blocks
+    are bitwise the per-edge gather-then-map form's and the dense
+    oracle's. Elsewhere the decision says so and the sweep's trace holds
+    no ``sweep_src_map`` equation. Every block of a slate at once (the
+    cold sweep's vmap), in the plan's layout and in a pool's."""
+    from repro.core.engine import (edge_data, make_lane_processor,
+                                   make_tiled_processor)
+    from repro.kernels import block_sweep as bs
+    factory, lanes, engaged = _SRC_MAP[prog]
+    program = factory()
+    g = G.powerlaw_graph(300, 5, seed=11, weighted=True)
+    eng = StructureAwareEngine(g, A.pagerank(), EngineConfig(
+        block_size=64, width=4, subblocks=subblocks))
+    plan, n = eng.plan, eng._values_len
+    assert bs._vertex_message(program, g.n, lanes) is engaged
+    store = plan.unified
+    ed = (edge_data(store, eng.aux, subblocks, plan.block_size)
+          if layout == "plan"
+          else _pool_layout(store, eng.aux, subblocks, plan.block_size))
+    rng = np.random.default_rng(subblocks)
+    vshape = (n, 2) if lanes else (n,)
+    values = jnp.asarray(rng.random(vshape, np.float32))
+    vconst = jnp.asarray(rng.random(vshape, np.float32))
+    rows = jnp.arange(plan.num_blocks, dtype=jnp.int32)
+    sub_act = (None if subblocks == 1 else jnp.asarray(
+        rng.random((plan.num_blocks, subblocks)) < 0.6))
+
+    def run(use_pallas):
+        if lanes:
+            lane_one = make_lane_processor(
+                program, store, plan.block_size, plan.n_live, g.n,
+                subblocks=subblocks, use_pallas=use_pallas)[0]
+
+            def one(r, s=None):
+                return lane_one(ed, values, vconst, r, s)
+        else:
+            tiled_one = make_tiled_processor(
+                program, store, plan.block_size, plan.n_live, g.n,
+                use_pallas, subblocks=subblocks)[0]
+
+            def one(r, s=None):
+                return tiled_one(ed, values, r, s)
+        swept = jax.vmap(one)
+        args = (rows,) if sub_act is None else (rows, sub_act)
+        scopes = _scope_names(jax.make_jaxpr(swept)(*args).jaxpr)
+        return [np.asarray(a) for a in jax.jit(swept)(*args)], scopes
+
+    got, scopes = run(True)
+    assert ("sweep_src_map" in scopes) is engaged, prog
+    monkeypatch.setattr(bs, "_BUILDER_CACHE", {})
+    monkeypatch.setattr(bs, "_vertex_message", lambda *a: False)
+    per_edge, pe_scopes = run(True)
+    assert "sweep_src_map" not in pe_scopes
+    dense, _ = run(False)
+    label = f"{prog} sb={subblocks} {layout}"
+    for a, b, c in zip(got, per_edge, dense):
+        assert np.array_equal(a, b), f"{label}: not the per-edge form's"
+        assert np.array_equal(a, c), f"{label}: not the dense oracle's"

@@ -150,9 +150,67 @@ def _op_name(line):
     return m.group(1) if m else ""
 
 
+# programs whose edge_map reads aux and not w: the message is mapped once
+# per vertex and gathered once per edge
+SRC_MAPPED = ("sum", "sum_s8", "ppr_lanes")
+
+
+def _in_loops(text):
+    """Instruction lines of a compiled module, each with whether it runs
+    inside a ``while`` body (directly or through the computations that
+    the body calls)."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            head = line.split()
+            cur = head[1] if head[0] == "ENTRY" else head[0]
+            comps[cur] = []
+        elif cur is not None:
+            comps[cur].append(line)
+    called = re.compile(r"(?:calls|body|condition|to_apply)=(%[\w.\-]+)"
+                        r"|branch_computations=\{([^}]*)\}")
+    todo = [b for body in comps.values() for line in body
+            for b in re.findall(r"body=(%[\w.\-]+)", line)]
+    looped = set()
+    while todo:
+        c = todo.pop()
+        if c in looped or c not in comps:
+            continue
+        looped.add(c)
+        for line in comps[c]:
+            for one, many in called.findall(line):
+                todo += [one] if one else re.findall(r"%[\w.\-]+", many)
+    return [(line, c in looped) for c, body in comps.items()
+            for line in body]
+
+
+@pytest.mark.parametrize("form", ["row", "vmap"])
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_message_gathered_once_for_v5e(variant, form, compiled_sweep):
+    """Each chunk gathers one array per edge, under
+    ``sweep_gather_values``, and never aux. Where ``edge_map`` ignores
+    ``w`` (PageRank, the PPR lanes) that array is the message mapped once
+    per vertex under ``sweep_src_map``, outside the chunk loop's body, so
+    the map is not fused back into the gather; SSSP gathers its values
+    and maps no vertex."""
+    from repro.obs import scopes
+    lines = _in_loops(compiled_sweep(variant, form))
+    gathers = [(scopes.scope_of(_op_name(line)), looped)
+               for line, looped in lines
+               if re.search(r"= \S+ gather\(", line)]
+    assert gathers.count(("sweep_gather_values", True)) == 1, gathers
+    assert not [g for g in gathers if g[0] == "sweep_gather_aux"], gathers
+    mapped = [looped for line, looped in lines
+              if scopes.scope_of(_op_name(line)) == "sweep_src_map"]
+    if variant in SRC_MAPPED:
+        assert mapped and not any(mapped), mapped
+    else:
+        assert not mapped
+
+
 def test_chunk_scopes_for_v5e(one_chip, no_cache, monkeypatch):
     """The engine's fused chunk compiled for v5e: every named scope of
-    the superstep reaches the compiled program, and every operation
+    PageRank's superstep reaches the compiled program, and every operation
     that does work maps to one of them (``op_scopes``' reading)."""
     from repro.core import graph as G
     from repro.core.engine import EngineConfig, StructureAwareEngine
@@ -171,7 +229,9 @@ def test_chunk_scopes_for_v5e(one_chip, no_cache, monkeypatch):
     args = jax.tree.map(spec, eng._idle_chunk_args(wb))
     text = eng._get_chunk(wb).lower(*args).compile().as_text()
     got = scopes.op_scopes(text)
-    assert set(scopes.SCOPES) <= set(got.values())
+    # PageRank maps its message per vertex: no aux is gathered per edge
+    assert set(scopes.SCOPES) - {"sweep_gather_aux"} <= set(got.values())
+    assert "sweep_gather_aux" not in got.values()
     idle = ("parameter(", "get-tuple-element(", "bitcast(", "constant(",
             "tuple(", "copy(", "copy-start(", "copy-done(", "custom-call()")
     for line in text.splitlines():
